@@ -103,4 +103,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils import init_compile_cache
+
+    init_compile_cache()
     main()

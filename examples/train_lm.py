@@ -96,4 +96,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils import init_compile_cache
+
+    init_compile_cache()
     main()

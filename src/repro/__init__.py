@@ -2,7 +2,8 @@
 storage subsystem of a multi-pod JAX training/inference framework.
 
 Layers (bottom-up):
-  repro.kernels   Pallas TPU kernels (+ jnp oracles) for codec/quality/warp hot-spots
+  repro.kernels   Pallas TPU kernels (+ jnp oracles) for codec/quality hot-spots,
+                  and the jitted XLA warp of joint compression
   repro.codec     GOP-based tensor video codec (TVC) with quality tiers
   repro.core      the paper's storage manager: catalog, cost/quality models,
                   fragment selection (greedy/DP/Z3), LRU_VSS cache, deferred

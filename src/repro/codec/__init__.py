@@ -8,6 +8,7 @@ from repro.codec.tvc import (  # noqa: F401
     decode_gop,
     deserialize_gop,
     encode_gop,
+    gop_planes,
     is_compressed_codec,
     parse_gop_header,
     prefix_gop,

@@ -173,8 +173,10 @@ def encode_gop(
             planar, q=tier.q, lo=tier.lo, hi=tier.hi, vmin=VMIN, vmax=VMAX,
             use_pallas=use_pallas,
         )
+        # narrow on the device: the residuals are clipped to the tier's
+        # range, so only a quarter of the int32 bytes cross to the host
         iframe = np.asarray(ifr, dtype=np.float32)
-        resid = np.asarray(res).astype(tier.resid_dtype)
+        resid = np.asarray(res.astype(tier.resid_dtype))
     # one independently-compressed chunk per frame (I-frame, then each
     # residual): a payload prefix [0, offsets[hi]) decodes frames
     # [0, hi) without touching — or even fetching — the rest
@@ -229,6 +231,21 @@ def prefix_gop(enc: EncodedGOP, hi: int) -> EncodedGOP:
                       enc.payload[: enc.offsets[hi]], enc.offsets[: hi + 1])
 
 
+def gop_planes(enc: EncodedGOP) -> Tuple[np.ndarray, np.ndarray]:
+    """The stored planes of a TVC GOP: the I-frame ``(C, H, W)`` uint8
+    and the quantized residuals ``(T-1, C, H, W)`` in the tier's dtype.
+
+    The payload is channel-planar, exactly as encoded: the I-frame
+    followed by the residuals."""
+    t, h, w, c = enc.shape
+    tier = TIERS[enc.codec]
+    raw = _raw_payload(enc)
+    isz = h * w * c
+    iframe = np.frombuffer(raw[:isz], np.uint8).reshape(c, h, w)
+    resid = np.frombuffer(raw[isz:], tier.resid_dtype).reshape(t - 1, c, h, w)
+    return iframe, resid
+
+
 def decode_gop(
     enc: EncodedGOP,
     *,
@@ -239,25 +256,23 @@ def decode_gop(
     if enc.codec == RGB:
         return np.frombuffer(enc.payload, np.uint8).reshape(t, h, w, c).copy()
     tier = TIERS[enc.codec]
-    raw = _raw_payload(enc)
-    isz = h * w * c
-    # payload is channel-planar, exactly as encoded: iframe (C,H,W) uint8
-    # followed by residuals (T-1,C,H,W)
-    iframe = np.frombuffer(raw[:isz], np.uint8).reshape(c, h, w).astype(np.float32)
-    resid = (
-        np.frombuffer(raw[isz:], tier.resid_dtype).reshape(t - 1, c, h, w)
-        if t > 1
-        else np.zeros((0, c, h, w), np.int32)
-    )
+    iframe, resid = gop_planes(enc)
+    # planes cross to the device in their stored dtypes and frames come
+    # back as uint8; every widening and narrowing happens on the device
+    iframe = jnp.asarray(iframe).astype(jnp.float32)
     if t == 1:
-        planar = jnp.asarray(iframe)[None]
+        planar = iframe[None]
     else:
         planar = ops.delta_decode(
-            jnp.asarray(iframe), jnp.asarray(resid.astype(np.int32)),
+            iframe, jnp.asarray(resid).astype(jnp.int32),
             q=tier.q, vmin=VMIN, vmax=VMAX, use_pallas=use_pallas,
         )
-    out = ops.from_planar(planar)
-    return np.asarray(jnp.clip(jnp.round(out), 0, 255), dtype=np.uint8)
+    return _to_u8(ops.from_planar(planar))
+
+
+def _to_u8(frames: jnp.ndarray) -> np.ndarray:
+    """Round and clip float frames on the device; download uint8."""
+    return np.asarray(jnp.clip(jnp.round(frames), 0, 255).astype(jnp.uint8))
 
 
 def transcode_gop(
@@ -287,31 +302,24 @@ def transcode_gop(
     if fused:
         tin = TIERS[enc.codec]
         tout = TIERS[codec]
-        raw = _raw_payload(enc)
-        isz = h * w * c
-        iframe = np.frombuffer(raw[:isz], np.uint8).reshape(c, h, w).astype(np.float32)
-        resid = (
-            np.frombuffer(raw[isz:], tin.resid_dtype)
-            .reshape(t - 1, c, h, w).astype(np.int32)
-        )
+        iframe, resid = gop_planes(enc)
         io, ro = ops.transcode(
-            jnp.asarray(iframe), jnp.asarray(resid),
+            jnp.asarray(iframe).astype(jnp.float32),
+            jnp.asarray(resid).astype(jnp.int32),
             q_in=tin.q, q_out=tout.q, factor=f,
             lo=tout.lo, hi=tout.hi, vmin=VMIN, vmax=VMAX,
             use_pallas=use_pallas,
         )
         oh, ow = h // f, w // f
         iframe_out = np.asarray(io, np.float32)
-        resid_out = np.asarray(ro).astype(tout.resid_dtype)
+        resid_out = np.asarray(ro.astype(tout.resid_dtype))
         return _chunked_gop(codec, (t, oh, ow, c),
                             iframe_out.astype(np.uint8), resid_out, tout)
     frames = decode_gop(enc, use_pallas=use_pallas)
     if f > 1:
         planar = ops.to_planar(jnp.asarray(frames))
         small = planar.reshape(t, c, h // f, f, w // f, f).mean(axis=(3, 5))
-        frames = np.asarray(
-            jnp.clip(jnp.round(ops.from_planar(small)), 0, 255), np.uint8
-        )
+        frames = _to_u8(ops.from_planar(small))
     return encode_gop(frames, codec, use_pallas=use_pallas)
 
 

@@ -21,6 +21,12 @@ PATCH = 8  # descriptor patch half-size → (2*PATCH)² dims
 LOWE_RATIO = 0.8  # Lowe's ratio disambiguation (§5.1.3)
 FEATURE_DIST = 400.0  # paper's d=400 Euclidean cutoff
 MIN_MATCHES = 20  # paper's m=20 correspondences
+# Features are detected on a box-pooled copy whose long side is at most
+# this: the detector's windows are fixed in pixels, and at 1080p the
+# strongest few hundred corners of a frame are repeated fine texture
+# that the ratio test rejects (SIFT gets the same robustness from its
+# scale pyramid).
+WORK_SIDE = 512
 
 
 def to_gray(img: np.ndarray) -> np.ndarray:
@@ -201,16 +207,34 @@ def ransac_homography(
     return h
 
 
+def work_scale(img: np.ndarray) -> int:
+    """Pooling factor that brings the long side to at most WORK_SIDE."""
+    return max(1, -(-max(img.shape[:2]) // WORK_SIDE))
+
+
+def pool_frame(img: np.ndarray, s: int) -> np.ndarray:
+    """(H, W, C) box-mean pooled by ``s`` (trailing partial cells cut)."""
+    if s == 1:
+        return img
+    h, w = img.shape[0] // s, img.shape[1] // s
+    x = img[: h * s, : w * s].astype(np.float32)
+    return x.reshape(h, s, w, s, -1).mean((1, 3))
+
+
 def estimate_homography(
     f: np.ndarray, g: np.ndarray, *, max_corners: int = 300, seed: int = 0
 ) -> Optional[np.ndarray]:
     """H mapping g's pixel coordinates into f's (``f(H@x) ≈ g(x)``).
 
-    Returns None when no confident homography exists (Algorithm 1 then
-    aborts joint compression for the pair).
+    Detects and matches at the working scale (`work_scale`, with
+    ``max_corners`` per pooling step) and maps H back to full-resolution
+    pixels. Returns None when no confident homography exists
+    (Algorithm 1 then aborts joint compression for the pair).
     """
-    ka = detect_corners(f, max_corners)
-    kb = detect_corners(g, max_corners)
+    s = work_scale(f)
+    f, g = pool_frame(f, s), pool_frame(g, s)
+    ka = detect_corners(f, max_corners * s)
+    kb = detect_corners(g, max_corners * s)
     da = describe(f, ka)
     db = describe(g, kb)
     matches = match_descriptors(da, db, mutual=True)
@@ -222,13 +246,22 @@ def estimate_homography(
         return None
     src = np.array([kb[j] for _, j in matches], np.float32)  # g coords
     dst = np.array([ka[i] for i, _ in matches], np.float32)  # f coords
-    return ransac_homography(src, dst, seed=seed)
+    h = ransac_homography(src, dst, seed=seed)
+    if h is None or s == 1:
+        return h
+    # pooled pixel i covers full pixels [s*i, s*i + s): x = s*x' + (s-1)/2
+    a = np.array([[s, 0, (s - 1) / 2], [0, s, (s - 1) / 2], [0, 0, 1]])
+    h = a @ h @ np.linalg.inv(a)
+    return (h / h[2, 2]).astype(np.float32)
 
 
 def count_correspondences(f: np.ndarray, g: np.ndarray) -> int:
-    """Number of unambiguous nearby feature correspondences (§5.1.3)."""
-    ka = detect_corners(f)
-    kb = detect_corners(g)
+    """Number of unambiguous nearby feature correspondences (§5.1.3),
+    counted at the working scale."""
+    s = work_scale(f)
+    f, g = pool_frame(f, s), pool_frame(g, s)
+    ka = detect_corners(f, 200 * s)
+    kb = detect_corners(g, 200 * s)
     da, db = describe(f, ka), describe(g, kb)
     n = len(match_descriptors(da, db, mutual=True))
     if n < MIN_MATCHES:

@@ -114,8 +114,9 @@ class ReadResult:
     pass-through reads (cache hit in the requested codec) never touch
     pixels unless the caller actually asks for them."""
 
-    def __init__(self, frames, codec, encoded, plan, fps):
+    def __init__(self, frames, codec, encoded, plan, fps, use_pallas=None):
         self._frames = frames
+        self._use_pallas = use_pallas
         self.codec = codec
         self.encoded: Optional[List[_codec.EncodedGOP]] = encoded
         self.plan: ReadPlan = plan
@@ -125,7 +126,8 @@ class ReadResult:
     def frames(self) -> np.ndarray:
         if self._frames is None:
             self._frames = np.concatenate(
-                [_codec.decode_gop(e) for e in self.encoded], axis=0
+                [_codec.decode_gop(e, use_pallas=self._use_pallas)
+                 for e in self.encoded], axis=0
             )
         return self._frames
 
@@ -873,7 +875,8 @@ class VSS:
                         0.0, (time.perf_counter() - t_exec) - fetch_s
                     )
                     root.children.append(dsp)
-            results[i] = ReadResult(frames, r.codec, encoded, plan, r.fps)
+            results[i] = ReadResult(frames, r.codec, encoded, plan, r.fps,
+                                    use_pallas=self.use_pallas)
 
         # -- cache admission + batched eviction/compaction (§4) ------------
         admitted_names: List[str] = []
@@ -1317,12 +1320,14 @@ class VSS:
             if gs >= f1 or ge <= f0:
                 continue
             gop_ids.append(g.gop_id)
-            if gs >= f0 and ge <= f1:  # fully inside: verbatim bytes
+            if gs >= f0 and ge <= f1 and g.joint_ref is None:
+                # fully inside: verbatim bytes (a jointly-compressed GOP
+                # has no object of its own and is rebuilt below)
                 data = (io or self.backend).get(g.path)
                 if is_wrapped(data):
                     data = unwrap_bytes(data)
                 out.append(_codec.deserialize_gop(data))
-            else:  # edge GOP: decode, trim, re-encode (the look-back cost)
+            else:  # edge or joint GOP: decode, trim, re-encode
                 lo = max(f0 - gs, 0)
                 hi = min(f1, ge) - gs
                 if self._trim_eligible(g, hi, p):
@@ -2111,4 +2116,4 @@ def resample(frames: np.ndarray, resolution: Tuple[int, int]) -> np.ndarray:
     out = jax.image.resize(
         jnp.asarray(frames, jnp.float32), (t, h, w, c), method="bilinear"
     )
-    return np.asarray(jnp.clip(jnp.round(out), 0, 255), np.uint8)
+    return np.asarray(jnp.clip(jnp.round(out), 0, 255).astype(jnp.uint8))

@@ -17,7 +17,7 @@ module procedurally generates equivalent structure at any scale:
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Tuple
+from typing import Iterator, List, Tuple
 
 import numpy as np
 
@@ -160,6 +160,28 @@ def _sample_view(
     )
 
 
+def road_gops(
+    num_frames: int,
+    gop_frames: int,
+    width: int = 192,
+    height: int = 108,
+    *,
+    n_cars: int = 6,
+    seed: int = 0,
+) -> Iterator[np.ndarray]:
+    """The frames of ``synthesize_road(num_frames, ...)``, yielded
+    ``gop_frames`` at a time as (T, H, W, 3) uint8, so a long or
+    high-resolution stream is never held whole on the host."""
+    rng = np.random.default_rng(seed)
+    world = make_world(rng, height, width)
+    cars = make_cars(rng, height, width, n_cars)
+    for start in range(0, num_frames, gop_frames):
+        stop = min(start + gop_frames, num_frames)
+        yield np.stack(
+            [render_panorama(world, cars, t) for t in range(start, stop)]
+        )
+
+
 def synthesize_road(
     num_frames: int,
     width: int = 192,
@@ -169,12 +191,10 @@ def synthesize_road(
     seed: int = 0,
 ) -> np.ndarray:
     """Single-camera clip (T, H, W, 3) uint8."""
-    rng = np.random.default_rng(seed)
-    world = make_world(rng, height, width)
-    cars = make_cars(rng, height, width, n_cars)
-    return np.stack(
-        [render_panorama(world, cars, t) for t in range(num_frames)]
-    )
+    return np.concatenate(list(road_gops(
+        num_frames, max(num_frames, 1), width, height,
+        n_cars=n_cars, seed=seed,
+    )))
 
 
 def synthesize_overlapping_pair(

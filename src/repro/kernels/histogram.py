@@ -1,8 +1,10 @@
 """Pallas per-channel histogram kernel (joint-compression fingerprints, §5.1.3).
 
-Grid = (N, C, H-tiles, W-tiles); the (1, 1, bins_padded) int32 output
-block is revisited across the spatial tiles ("arbitrary" semantics) and
-accumulated in place — the canonical TPU reduction-across-grid pattern.
+Grid = (N, C, H-tiles, W-tiles); the (1, C, bins_padded) int32 output
+block holds every channel of one frame (a block's last two dims must be
+(8, 128)-aligned or full), so it is revisited across the channel and
+spatial axes ("arbitrary" semantics) and accumulated in place — the
+canonical TPU reduction-across-grid pattern.
 Bin counting is B masked VPU reductions (one compare+sum per bin), which
 beats a scatter on TPU since there is no atomic HBM scatter-add.
 
@@ -16,7 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 DEFAULT_BH = 8
@@ -24,10 +26,11 @@ DEFAULT_BW = 128
 
 
 def _hist_kernel(frames_ref, out_ref, *, bins, vmax, h_valid, w_valid, bh, bw):
+    ci = pl.program_id(1)
     i = pl.program_id(2)
     j = pl.program_id(3)
 
-    @pl.when((i == 0) & (j == 0))
+    @pl.when((ci == 0) & (i == 0) & (j == 0))
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -38,10 +41,13 @@ def _hist_kernel(frames_ref, out_ref, *, bins, vmax, h_valid, w_valid, bh, bw):
     cols = j * bw + jax.lax.broadcasted_iota(jnp.int32, (bh, bw), 1)
     valid = (rows < h_valid) & (cols < w_valid)
 
-    # one-hot matmul-style count: (bh*bw, 1) vs (1, bins_padded) compare
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (bh, bw, out_ref.shape[2]), 2)
-    onehot = (lanes == idx[:, :, None]) & valid[:, :, None]
-    out_ref[0, 0] += onehot.astype(jnp.int32).sum(axis=(0, 1))
+    # one masked full reduction per bin, scattered into its lane
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, out_ref.shape[2]), 1)
+    counts = jnp.zeros((1, out_ref.shape[2]), jnp.int32)
+    for b in range(bins):
+        n = jnp.sum(((idx == b) & valid).astype(jnp.int32))
+        counts = counts + jnp.where(lanes == b, n, 0)
+    out_ref[0, pl.ds(ci, 1), :] += counts
 
 
 @functools.partial(
@@ -75,11 +81,11 @@ def histogram_pallas(
             pl.BlockSpec((1, 1, bh, bw), lambda ni, ci, i, j: (ni, ci, i, j)),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, bins_padded), lambda ni, ci, i, j: (ni, ci, 0)
+            (1, c, bins_padded), lambda ni, ci, i, j: (ni, 0, 0)
         ),
         out_shape=jax.ShapeDtypeStruct((n, c, bins_padded), jnp.int32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
     )(frames.astype(jnp.float32))

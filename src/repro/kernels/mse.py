@@ -1,10 +1,12 @@
 """Pallas fused per-frame sum-of-squared-error kernel (quality model §3.2).
 
-Grid = (N, H-tiles, W-tiles); a (1, 1) f32 SMEM scalar block per frame is
-accumulated across spatial tiles. Differences are squared and reduced in
-f32 while both tiles are VMEM-resident, so quality checks cost a single
-read of each operand — this backs PSNR/MSE tracking for every cached
-fragment and the joint-compression verify step.
+Grid = (N, H-tiles, W-tiles); a (1, bh, bw) f32 block per frame is
+accumulated elementwise across spatial tiles (a (1, 1) scalar block per
+frame would break the rule that a block's last two dims are (8, 128)-
+aligned or full), and the wrapper sums the partial block. Differences are
+squared in f32 while both tiles are VMEM-resident, so a quality check
+costs a single read of each operand. Only the tests call it today; the
+store's quality checks run in numpy.
 """
 from __future__ import annotations
 
@@ -13,7 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BH = 8
 DEFAULT_BW = 128
@@ -25,7 +27,7 @@ def _mse_kernel(a_ref, b_ref, out_ref, *, h_valid, w_valid, bh, bw):
 
     @pl.when((i == 0) & (j == 0))
     def _init():
-        out_ref[0, 0] = 0.0
+        out_ref[...] = jnp.zeros_like(out_ref)
 
     a = a_ref[0].astype(jnp.float32)
     b = b_ref[0].astype(jnp.float32)
@@ -33,7 +35,7 @@ def _mse_kernel(a_ref, b_ref, out_ref, *, h_valid, w_valid, bh, bw):
     cols = j * bw + jax.lax.broadcasted_iota(jnp.int32, (bh, bw), 1)
     valid = (rows < h_valid) & (cols < w_valid)
     d = jnp.where(valid, a - b, 0.0)
-    out_ref[0, 0] += jnp.sum(d * d)
+    out_ref[0] += d * d
 
 
 @functools.partial(
@@ -63,13 +65,11 @@ def mse_sum_pallas(
             pl.BlockSpec((1, bh, bw), lambda ni, i, j: (ni, i, j)),
             pl.BlockSpec((1, bh, bw), lambda ni, i, j: (ni, i, j)),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1), lambda ni, i, j: (ni, 0), memory_space=pltpu.SMEM
-        ),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, bh, bw), lambda ni, i, j: (ni, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, bh, bw), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
     )(a.astype(jnp.float32), b.astype(jnp.float32))
-    return out[:, 0]
+    return out.sum(axis=(1, 2))

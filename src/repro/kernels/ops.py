@@ -5,14 +5,23 @@ Responsibilities:
     uint8; kernels are channel-planar (T, C, H, W) f32,
   * padding H→multiple of 8 and W→multiple of 128 (TPU sublane/lane
     tiles) and unpadding the results,
-  * dispatch: Pallas kernel (interpret=True off-TPU) vs. the jnp oracle
-    (``use_pallas=False``, used as the paper-faithful baseline and in
-    differential tests).
+  * dispatch: the compiled Pallas kernel on TPU, interpret-mode Pallas
+    or the jnp oracle elsewhere (``use_pallas=False`` forces the oracle,
+    the paper-faithful baseline used in differential tests).
+
+Every call counts the path it took in the process registry as
+``vss_kernel_dispatch_total{op=..., path=pallas|interpret|oracle|xla}``,
+so a run can prove which implementation served it. The homography warp
+has one implementation on every platform: a jitted XLA program (a
+bilinear warp is a data-dependent 2-D gather, which Mosaic cannot
+lower), counted as ``path="xla"``.
 
 Every function here has a matching oracle in :mod:`repro.kernels.ref`.
 """
 from __future__ import annotations
 
+import os
+import threading
 from typing import Tuple
 
 import jax
@@ -24,28 +33,68 @@ from repro.kernels import histogram as _hist
 from repro.kernels import mse as _mse
 from repro.kernels import ref
 from repro.kernels import transcode as _tc
-from repro.kernels import warp as _warp
 
 SUBLANE = 8
 LANE = 128
 
+DISPATCH_METRIC = "vss_kernel_dispatch_total"
 
-def _resolve_use_pallas(use_pallas):
-    """None → auto: Pallas on TPU (or REPRO_FORCE_PALLAS=1), oracle elsewhere.
+_dispatch_lock = threading.Lock()
+_dispatch_counters: dict = {}
 
-    Interpret-mode Pallas is a correctness tool, not a fast path; the
-    jnp oracles are jit-compiled and are the CPU production path.
+
+def _count(op: str, path: str) -> None:
+    """Count one call of ``op`` served by ``path`` (default registry)."""
+    key = (op, path)
+    counter = _dispatch_counters.get(key)
+    if counter is None:
+        from repro.obs import default_registry
+
+        with _dispatch_lock:
+            counter = _dispatch_counters.get(key)
+            if counter is None:
+                counter = default_registry().counter(
+                    DISPATCH_METRIC,
+                    "codec/joint kernel calls by the implementation that ran",
+                    labels={"op": op, "path": path},
+                )
+                _dispatch_counters[key] = counter
+    counter.inc()
+
+
+def dispatch_counts() -> dict:
+    """``{(op, path): calls}`` so far in this process (default registry)."""
+    from repro.obs import default_registry
+
+    reg = default_registry()
+    with _dispatch_lock:
+        keys = list(_dispatch_counters)
+    return {
+        k: reg.value(DISPATCH_METRIC, {"op": k[0], "path": k[1]})
+        for k in keys
+    }
+
+
+def _resolve(op: str, use_pallas, interpret):
+    """Pick and count the path of one call; returns ``interpret`` or
+    None for the oracle.
+
+    ``use_pallas=None`` means Pallas on TPU (or with
+    REPRO_FORCE_PALLAS=1) and the jit-compiled jnp oracle elsewhere:
+    interpret-mode Pallas is a correctness tool, not a fast path.
     """
-    if use_pallas is not None:
-        return use_pallas
-    import os
-    if os.environ.get("REPRO_FORCE_PALLAS") == "1":
-        return True
-    return jax.default_backend() == "tpu"
+    if use_pallas is None:
+        use_pallas = (os.environ.get("REPRO_FORCE_PALLAS") == "1"
+                      or jax.default_backend() == "tpu")
+    if not use_pallas:
+        _count(op, "oracle")
+        return None
+    interpret = utils.interpret_default() if interpret is None else interpret
+    _count(op, "interpret" if interpret else "pallas")
+    return interpret
 
-# VMEM budget used to decide whether the warp kernel's resident source
-# plane fits (16 MiB/core on v5e, keep headroom for output + spill).
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+
+_warp_xla = jax.jit(ref.warp, static_argnames=("out_shape",))
 
 
 def to_planar(frames: jnp.ndarray) -> jnp.ndarray:
@@ -78,10 +127,9 @@ def delta_encode(
     use_pallas: bool | None = None,
     interpret: bool | None = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    use_pallas = _resolve_use_pallas(use_pallas)
-    if not use_pallas:
+    interpret = _resolve("delta_encode", use_pallas, interpret)
+    if interpret is None:
         return ref.delta_encode(frames, q=q, lo=lo, hi=hi, vmin=vmin, vmax=vmax)
-    interpret = utils.interpret_default() if interpret is None else interpret
     padded, h, w = _pad_hw(frames)
     iframe, resid = _dc.delta_encode_pallas(
         padded, q=q, lo=lo, hi=hi, vmin=vmin, vmax=vmax, interpret=interpret
@@ -99,10 +147,9 @@ def delta_decode(
     use_pallas: bool | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    use_pallas = _resolve_use_pallas(use_pallas)
-    if not use_pallas:
+    interpret = _resolve("delta_decode", use_pallas, interpret)
+    if interpret is None:
         return ref.delta_decode(iframe, residuals, q=q, vmin=vmin, vmax=vmax)
-    interpret = utils.interpret_default() if interpret is None else interpret
     ipad, h, w = _pad_hw(iframe)
     rpad, _, _ = _pad_hw(residuals)
     frames = _dc.delta_decode_pallas(
@@ -126,13 +173,12 @@ def transcode(
     interpret: bool | None = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused decode→downsample→encode. Requires factor | H and factor | W."""
-    use_pallas = _resolve_use_pallas(use_pallas)
-    if not use_pallas:
+    interpret = _resolve("transcode", use_pallas, interpret)
+    if interpret is None:
         return ref.transcode(
             iframe, residuals, q_in=q_in, q_out=q_out, factor=factor,
             lo=lo, hi=hi, vmin=vmin, vmax=vmax,
         )
-    interpret = utils.interpret_default() if interpret is None else interpret
     c, h, w = iframe.shape
     # output tiles must be (8,128)-aligned => input padded to factor*(8,128)
     ipad = utils.pad_to_multiple(
@@ -154,33 +200,11 @@ def warp(
     hmat_inv: jnp.ndarray,  # (3, 3)
     *,
     out_shape: Tuple[int, int] | None = None,
-    use_pallas: bool | None = None,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    c, h, w = img.shape
-    oh, ow = out_shape if out_shape is not None else (h, w)
-    src_bytes = h * utils.round_up(w, LANE) * 4
-    use_pallas = _resolve_use_pallas(use_pallas)
-    if not use_pallas or src_bytes > VMEM_BUDGET_BYTES:
-        # source plane would not fit VMEM on real TPU — jnp fallback
-        return ref.warp(img, hmat_inv, out_shape=(oh, ow))
-    interpret = utils.interpret_default() if interpret is None else interpret
-    ipad, _, _ = _pad_hw(img)
-    ohp = utils.round_up(oh, SUBLANE)
-    owp = utils.round_up(ow, LANE)
-    # padded source columns are zero-filled; the kernel bounds-checks
-    # against the *padded* extent, so restrict sampling to the valid area
-    # by warping on the unpadded extent masked afterwards. Simpler: warp
-    # via kernel then zero out samples that fell in the pad margin is
-    # wrong (bilinear blends). Instead pass the padded image but clamp
-    # validity to (h, w) by pre-zeroing pads (already zero) and accepting
-    # <=1px edge blend at the pad border, matching the oracle by padding
-    # the oracle identically in tests. For store-internal use the pad
-    # border is masked by ROI handling.
-    out = _warp.warp_pallas(
-        ipad, hmat_inv, out_shape=(ohp, owp), interpret=interpret
-    )
-    return out[:, :oh, :ow]
+    """Bilinear homography warp (semantics of ``ref.warp``), jitted."""
+    _count("warp", "xla")
+    oh, ow = out_shape if out_shape is not None else img.shape[1:]
+    return _warp_xla(img, hmat_inv, out_shape=(int(oh), int(ow)))
 
 
 def histogram(
@@ -191,10 +215,9 @@ def histogram(
     use_pallas: bool | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    use_pallas = _resolve_use_pallas(use_pallas)
-    if not use_pallas:
+    interpret = _resolve("histogram", use_pallas, interpret)
+    if interpret is None:
         return ref.histogram(frames, bins=bins, vmax=vmax)
-    interpret = utils.interpret_default() if interpret is None else interpret
     padded, h, w = _pad_hw(frames)
     return _hist.histogram_pallas(
         padded, bins=bins, vmax=vmax, h_valid=h, w_valid=w, interpret=interpret
@@ -208,10 +231,9 @@ def mse_sum(
     use_pallas: bool | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    use_pallas = _resolve_use_pallas(use_pallas)
-    if not use_pallas:
+    interpret = _resolve("mse_sum", use_pallas, interpret)
+    if interpret is None:
         return ref.mse_sum(a, b)
-    interpret = utils.interpret_default() if interpret is None else interpret
     apad, h, w = _pad_hw(a)
     bpad, _, _ = _pad_hw(b)
     return _mse.mse_sum_pallas(
@@ -248,12 +270,11 @@ def paged_decode_attention(
 ) -> jnp.ndarray:
     from repro.kernels.paged_attention import paged_decode_attention_pallas
 
-    use_pallas = _resolve_use_pallas(use_pallas)
-    if not use_pallas:
+    interpret = _resolve("paged_decode_attention", use_pallas, interpret)
+    if interpret is None:
         return ref.paged_decode_attention(
             q, k_pages, v_pages, block_table, seq_lens, scale=scale
         )
-    interpret = utils.interpret_default() if interpret is None else interpret
     return paged_decode_attention_pallas(
         q, k_pages, v_pages, block_table, seq_lens,
         scale=scale, interpret=interpret,

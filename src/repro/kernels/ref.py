@@ -135,11 +135,14 @@ def warp(
     c, h, w = img.shape
     oh, ow = out_shape if out_shape is not None else (h, w)
     ys, xs = jnp.mgrid[0:oh, 0:ow]
-    ones = jnp.ones_like(xs)
-    pts = jnp.stack([xs, ys, ones], axis=0).reshape(3, -1).astype(jnp.float32)
-    src = hmat_inv.astype(jnp.float32) @ pts
-    sx = src[0] / src[2]
-    sy = src[1] / src[2]
+    xs = xs.reshape(-1).astype(jnp.float32)
+    ys = ys.reshape(-1).astype(jnp.float32)
+    # elementwise, not a matmul: a TPU runs an f32 matmul at bf16
+    # precision by default, which would move 1080p coordinates by pixels
+    m = hmat_inv.astype(jnp.float32).reshape(9)
+    den = m[6] * xs + m[7] * ys + m[8]
+    sx = (m[0] * xs + m[1] * ys + m[2]) / den
+    sy = (m[3] * xs + m[4] * ys + m[5]) / den
 
     x0 = jnp.floor(sx)
     y0 = jnp.floor(sy)

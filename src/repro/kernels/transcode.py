@@ -19,18 +19,36 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BH = 8
 DEFAULT_BW = 128
 
 
 def _pool(x: jnp.ndarray, factor: int) -> jnp.ndarray:
+    """Box-mean ``(f*bh, f*bw) -> (bh, bw)`` as two 0/1-weighted matmuls.
+
+    Mosaic cannot lower the reshape a strided mean needs, so the rows and
+    then the columns are pooled through pooling matrices on the MXU. At
+    HIGHEST precision every product and partial sum is exact for the
+    codec's integer-valued planes, so the result equals the oracle's
+    ``reshape(...).mean(...)`` bit for bit.
+    """
     if factor == 1:
         return x
     h, w = x.shape
-    x = x.reshape(h // factor, factor, w // factor, factor)
-    return x.mean(axis=(1, 3))
+    bh, bw = h // factor, w // factor
+    r = jax.lax.broadcasted_iota(jnp.int32, (bh, h), 0)
+    k = jax.lax.broadcasted_iota(jnp.int32, (bh, h), 1)
+    rows = jnp.where((k >= r * factor) & (k < r * factor + factor),
+                     1.0 / factor, 0.0).astype(jnp.float32)
+    k = jax.lax.broadcasted_iota(jnp.int32, (w, bw), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (w, bw), 1)
+    cols = jnp.where((k >= c * factor) & (k < c * factor + factor),
+                     1.0 / factor, 0.0).astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    y = jnp.dot(rows, x, precision=hi, preferred_element_type=jnp.float32)
+    return jnp.dot(y, cols, precision=hi, preferred_element_type=jnp.float32)
 
 
 def _transcode_kernel(

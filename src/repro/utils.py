@@ -2,6 +2,8 @@
 from __future__ import annotations
 
 import math
+import os
+from pathlib import Path
 from typing import Iterable, Sequence
 
 import jax
@@ -58,8 +60,32 @@ def human_bytes(n: float) -> str:
 
 
 def interpret_default() -> bool:
-    """Pallas kernels run in interpret mode off-TPU (this container is CPU)."""
+    """Pallas kernels are compiled by Mosaic on a TPU and run in
+    interpret mode on any other backend (the CPU test suite)."""
     return jax.default_backend() != "tpu"
+
+
+# the checkout root (src/repro/utils.py -> parents[2])
+REPO_ROOT = Path(__file__).resolve().parents[2]
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def init_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; entry points only.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX keeps its cache there
+    and no other directory is set here. Otherwise the cache lives at the
+    fixed ``<checkout>/.jax_cache`` (git-ignored), never a temp, pid- or
+    time-derived path, so later runs of the same checkout find it. Every
+    compile is cached: the codec kernels compile in well under JAX's
+    default one-second threshold. Returns the cache directory.
+    """
+    path = os.environ.get(COMPILE_CACHE_ENV)
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 
 def prod(xs: Iterable[int]) -> int:

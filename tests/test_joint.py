@@ -83,3 +83,20 @@ def test_homography_estimation_accuracy(overlap_pair):
     p_true /= p_true[2]
     p_est /= p_est[2]
     assert np.abs(p_true - p_est).max() < 3.0  # within 3 px
+
+
+def test_joint_video_reads_in_its_stored_codec(vss, overlap_pair):
+    """A read in the stored codec re-encodes jointly-compressed GOPs:
+    their original objects are gone, so there are no bytes to pass
+    through verbatim."""
+    left, right, _ = overlap_pair
+    _write_pair(vss, left, right)
+    assert vss.apply_joint_compression(
+        ["cam_l", "cam_r"], merge="unprojected", tau_db=24.0
+    )
+    for name, src in (("cam_l", left), ("cam_r", right)):
+        out = vss.read(name, codec="tvc-hi", cache=False)
+        assert out.encoded and all(e.codec == "tvc-hi" for e in out.encoded)
+        rgb = vss.read(name, codec="rgb", cache=False).frames
+        assert out.frames.shape == rgb.shape == src.shape
+        assert exact_psnr(out.frames, rgb) >= 45.0

@@ -1,5 +1,12 @@
 """Per-kernel differential tests: Pallas (interpret mode) vs jnp oracle,
-swept over shapes/dtypes (the kernel contract in kernels/ref.py)."""
+swept over shapes/dtypes (the kernel contract in kernels/ref.py). The
+warp has a single jitted XLA implementation, checked against a numpy
+bilinear reference."""
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -49,17 +56,38 @@ def test_transcode_fused_matches_oracle(factor, q_in, q_out):
     np.testing.assert_array_equal(np.asarray(ro_p), np.asarray(ro_r))
 
 
+def _np_warp(img, hmat_inv, oh, ow):
+    """Plain numpy bilinear warp with zero fill outside the source."""
+    c, h, w = img.shape
+    ys, xs = np.mgrid[0:oh, 0:ow].astype(np.float32).reshape(2, -1)
+    m = hmat_inv.astype(np.float32).reshape(9)
+    den = m[6] * xs + m[7] * ys + m[8]
+    sx = (m[0] * xs + m[1] * ys + m[2]) / den
+    sy = (m[3] * xs + m[4] * ys + m[5]) / den
+    x0, y0 = np.floor(sx), np.floor(sy)
+    fx, fy = sx - x0, sy - y0
+    x0, y0 = x0.astype(np.int64), y0.astype(np.int64)
+    out = np.zeros((c, oh * ow), np.float32)
+    for dy, dx, wt in ((0, 0, (1 - fy) * (1 - fx)), (0, 1, (1 - fy) * fx),
+                       (1, 0, fy * (1 - fx)), (1, 1, fy * fx)):
+        yi, xi = y0 + dy, x0 + dx
+        ok = (yi >= 0) & (yi < h) & (xi >= 0) & (xi < w)
+        vals = img[:, np.clip(yi, 0, h - 1), np.clip(xi, 0, w - 1)]
+        out += np.where(ok, vals, 0.0) * wt
+    return out.reshape(c, oh, ow)
+
+
 @pytest.mark.parametrize("h,w,oh,ow", [
     (32, 160, 32, 160), (24, 136, 16, 128), (64, 256, 40, 200),
 ])
 def test_warp_matches_oracle(h, w, oh, ow):
     img = frames(1, 3, h, w)[0]
-    hmat = jnp.asarray(np.array(
+    hmat = np.array(
         [[1.02, 0.03, 2.0], [0.01, 0.99, -1.5], [2e-5, 1e-5, 1.0]],
         np.float32,
-    ))
-    wp = ops.warp(img, hmat, out_shape=(oh, ow), use_pallas=True)
-    wr = ref.warp(img, hmat, out_shape=(oh, ow))
+    )
+    wp = ops.warp(img, jnp.asarray(hmat), out_shape=(oh, ow))
+    wr = _np_warp(np.asarray(img), hmat, oh, ow)
     np.testing.assert_allclose(wp, wr, atol=5e-2)
 
 
@@ -96,6 +124,58 @@ def test_paged_attention_matches_oracle(b, hq, hkv, d, p, page):
     op = ops.paged_decode_attention(q, kp, vp, bt, sl, use_pallas=True)
     orf = ref.paged_decode_attention(q, kp, vp, bt, sl)
     np.testing.assert_allclose(op, orf, atol=1e-4)
+
+
+def test_dispatch_counts_record_the_path():
+    """Every ops call counts the implementation that served it."""
+    x = frames(3, 3, 8, 128)
+    kw = dict(q=8.0, lo=-128, hi=127, vmin=0, vmax=255)
+    before = ops.dispatch_counts()
+    ops.delta_encode(x, use_pallas=False, **kw)
+    ops.delta_encode(x, use_pallas=True, **kw)  # interpret mode off-TPU
+    ops.warp(x[0], jnp.eye(3))
+    after = ops.dispatch_counts()
+    delta = {k: v - before.get(k, 0) for k, v in after.items()}
+    assert delta[("delta_encode", "oracle")] == 1
+    assert delta[("delta_encode", "interpret")] == 1
+    assert delta[("warp", "xla")] == 1
+    assert delta.get(("delta_encode", "pallas"), 0) == 0
+
+
+_CACHE_PROBE = """
+import json, jax, jax.numpy as jnp
+from repro.utils import REPO_ROOT, init_compile_cache
+path = init_compile_cache()
+if {compile}:
+    jax.jit(lambda v: jnp.sin(v) * 3)(jnp.ones(7)).block_until_ready()
+print(json.dumps([path, jax.config.jax_compilation_cache_dir,
+                  jax.config.jax_persistent_cache_min_compile_time_secs,
+                  str(REPO_ROOT)]))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins; unset, the cache sits at the
+    checkout's fixed, git-ignored .jax_cache."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE.format(compile=from_env)],
+        env=env, capture_output=True, text=True, timeout=120, check=True,
+    )
+    path, configured, min_secs, root = json.loads(out.stdout.splitlines()[-1])
+    assert configured == path and min_secs == 0
+    if from_env:
+        assert path == str(tmp_path / "cache")
+        assert os.listdir(path), "nothing was cached where the env said"
+    else:
+        assert path == os.path.join(root, ".jax_cache")
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
 
 
 def test_codec_roundtrip_through_gop_layer():

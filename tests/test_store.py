@@ -5,6 +5,16 @@ import pytest
 from repro.core.quality import exact_psnr
 
 
+def test_road_gops_stream_the_clip():
+    from repro.data.video import road_gops, synthesize_road
+
+    parts = list(road_gops(25, 10, 96, 64, seed=3))
+    assert [p.shape for p in parts] == [(10, 64, 96, 3)] * 2 + [(5, 64, 96, 3)]
+    np.testing.assert_array_equal(
+        np.concatenate(parts), synthesize_road(25, 96, 64, seed=3)
+    )
+
+
 def test_write_read_roundtrip_lossless(vss, clip):
     vss.write("v", clip, fps=30.0, codec="tvc-ll")
     out = vss.read("v", codec="rgb", cache=False).frames
