@@ -41,6 +41,7 @@ except ImportError:  # pragma: no cover - environment-dependent
     zstandard = None
 
 from repro.kernels import ops
+from repro.obs import Tracer
 
 RGB = "rgb"  # raw uncompressed uint8 frames
 
@@ -236,13 +237,18 @@ def gop_planes(enc: EncodedGOP) -> Tuple[np.ndarray, np.ndarray]:
     and the quantized residuals ``(T-1, C, H, W)`` in the tier's dtype.
 
     The payload is channel-planar, exactly as encoded: the I-frame
-    followed by the residuals."""
+    followed by the residuals.  Inside a traced read this is the
+    ``inflate`` stage."""
     t, h, w, c = enc.shape
     tier = TIERS[enc.codec]
-    raw = _raw_payload(enc)
-    isz = h * w * c
-    iframe = np.frombuffer(raw[:isz], np.uint8).reshape(c, h, w)
-    resid = np.frombuffer(raw[isz:], tier.resid_dtype).reshape(t - 1, c, h, w)
+    with Tracer.stage("inflate", bytes_in=len(enc.payload)) as sp:
+        raw = _raw_payload(enc)
+        isz = h * w * c
+        iframe = np.frombuffer(raw[:isz], np.uint8).reshape(c, h, w)
+        resid = np.frombuffer(raw[isz:], tier.resid_dtype).reshape(
+            t - 1, c, h, w)
+        if sp is not None:
+            sp.attrs["bytes_out"] = len(raw)
     return iframe, resid
 
 
@@ -251,23 +257,36 @@ def decode_gop(
     *,
     use_pallas: Optional[bool] = None,
 ) -> np.ndarray:
-    """Returns (T, H, W, C) uint8 frames."""
+    """Returns (T, H, W, C) uint8 frames.
+
+    Inside a traced read a TVC GOP's host time falls in three stages:
+    ``inflate`` (`gop_planes`), ``upload`` (the planes handed to JAX)
+    and ``download`` (from the first device op's dispatch to the uint8
+    frames on the host); an RGB GOP's copy is its ``inflate``."""
     t, h, w, c = enc.shape
     if enc.codec == RGB:
-        return np.frombuffer(enc.payload, np.uint8).reshape(t, h, w, c).copy()
+        n = len(enc.payload)
+        with Tracer.stage("inflate", bytes_in=n, bytes_out=n):
+            return np.frombuffer(enc.payload, np.uint8).reshape(
+                t, h, w, c).copy()
     tier = TIERS[enc.codec]
     iframe, resid = gop_planes(enc)
     # planes cross to the device in their stored dtypes and frames come
     # back as uint8; every widening and narrowing happens on the device
-    iframe = jnp.asarray(iframe).astype(jnp.float32)
-    if t == 1:
-        planar = iframe[None]
-    else:
-        planar = ops.delta_decode(
-            iframe, jnp.asarray(resid).astype(jnp.int32),
-            q=tier.q, vmin=VMIN, vmax=VMAX, use_pallas=use_pallas,
-        )
-    return _to_u8(ops.from_planar(planar))
+    with Tracer.stage("upload", bytes=iframe.nbytes + resid.nbytes):
+        iframe = jnp.asarray(iframe)
+        if t > 1:
+            resid = jnp.asarray(resid)
+    with Tracer.stage("download", bytes=t * h * w * c):
+        iframe = iframe.astype(jnp.float32)
+        if t == 1:
+            planar = iframe[None]
+        else:
+            planar = ops.delta_decode(
+                iframe, resid.astype(jnp.int32),
+                q=tier.q, vmin=VMIN, vmax=VMAX, use_pallas=use_pallas,
+            )
+        return _to_u8(ops.from_planar(planar))
 
 
 def _to_u8(frames: jnp.ndarray) -> np.ndarray:

@@ -41,6 +41,7 @@ transparency as an actually swappable layer.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -48,7 +49,7 @@ import threading
 import time
 import warnings
 from collections.abc import Mapping as _Mapping
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -102,7 +103,6 @@ class ReadPlan:
     problem: SelectionProblem
     selection: Selection
     runs: List["Run"]  # indexed by SegmentChoice.video_idx
-    plan_seconds: float
 
     def run_idx(self, seg_i: int) -> int:
         choice_i = self.selection.assignment[seg_i]
@@ -206,7 +206,9 @@ class _BatchIO:
     first fetch (`VSS._read_batch` attributes group fetches back to
     individual specs from it); ``claimed`` tracks which planned keys
     have already been attributed; ``gops_decoded`` counts real decodes
-    (cache hits are free)."""
+    (cache hits are free).  A round-trip during a spec's execution is
+    an ``inline`` ``fetch`` stage of its ``decode`` span; a group's
+    prefetch is traced by `VSS._read_batch`."""
 
     def __init__(self, backend: _storage.StorageBackend, *,
                  stream: bool = False):
@@ -221,14 +223,28 @@ class _BatchIO:
         self.fetched_sizes: Dict[str, int] = {}
         self.claimed: set = set()
 
-    def _fetch(self, keys: List[str]) -> List[bytes]:
-        t0 = time.perf_counter()
-        blobs = self.backend.batch_get(keys)
-        self.fetch_seconds += time.perf_counter() - t0
-        self.objects_fetched += len(keys)
-        for k, b in zip(keys, blobs):
-            self.bytes_fetched += len(b)
-            self.fetched_sizes.setdefault(k, len(b))
+    @contextlib.contextmanager
+    def _roundtrip(self, objects: int, inline: bool = True) -> Iterator[None]:
+        """Times one backend round-trip into ``fetch_seconds`` and, when
+        ``inline``, as an ``inline`` ``fetch`` stage of the current span
+        (if any); the body counts the bytes it fetched."""
+        bytes0 = self.bytes_fetched
+        stage = (_obs.Tracer.stage("fetch", inline=True, objects=objects)
+                 if inline else contextlib.nullcontext())
+        with stage as sp:
+            t0 = time.perf_counter()
+            yield
+            self.fetch_seconds += time.perf_counter() - t0
+            self.objects_fetched += objects
+            if sp is not None:
+                sp.attrs["bytes"] = self.bytes_fetched - bytes0
+
+    def _fetch(self, keys: List[str], inline: bool = True) -> List[bytes]:
+        with self._roundtrip(len(keys), inline):
+            blobs = self.backend.batch_get(keys)
+            for k, b in zip(keys, blobs):
+                self.bytes_fetched += len(b)
+                self.fetched_sizes.setdefault(k, len(b))
         return blobs
 
     def remember(self, gop_id: int, frames: np.ndarray) -> None:
@@ -238,20 +254,21 @@ class _BatchIO:
         if not self.stream:
             self.decoded[gop_id] = frames
 
-    def prefetch(self, keys: Sequence[str]) -> None:
+    def prefetch(self, keys: Sequence[str], inline: bool = False) -> None:
+        """One round-trip for the keys not held yet.  `VSS._read_batch`
+        traces a group's prefetch before execution itself; one made
+        during execution is ``inline``."""
         missing = [k for k in dict.fromkeys(keys) if k not in self.blobs]
         if missing:
-            self.blobs.update(zip(missing, self._fetch(missing)))
+            self.blobs.update(zip(missing, self._fetch(missing, inline)))
 
     def get(self, key: str) -> bytes:
         if key in self.blobs:
             return self.blobs[key]
-        t0 = time.perf_counter()
-        data = self.backend.get(key)
-        self.fetch_seconds += time.perf_counter() - t0
-        self.objects_fetched += 1
-        self.bytes_fetched += len(data)
-        self.fetched_sizes.setdefault(key, len(data))
+        with self._roundtrip(1):
+            data = self.backend.get(key)
+            self.bytes_fetched += len(data)
+            self.fetched_sizes.setdefault(key, len(data))
         if not self.stream:
             self.blobs[key] = data
         return data
@@ -261,18 +278,16 @@ class _BatchIO:
             uniq = [k for k in dict.fromkeys(keys)]
             got = dict(zip(uniq, self._fetch(uniq))) if uniq else {}
             return [got[k] for k in keys]
-        self.prefetch(keys)
+        self.prefetch(keys, inline=True)
         return [self.blobs[k] for k in keys]
 
     def get_range(self, key: str, start: int, length: int) -> bytes:
         """Ranged fetch with the same telemetry as ``get``.  Partial
         bytes are never cached in ``blobs`` — a later full read of the
         key must not alias a truncated payload."""
-        t0 = time.perf_counter()
-        data = self.backend.get_range(key, start, length)
-        self.fetch_seconds += time.perf_counter() - t0
-        self.objects_fetched += 1
-        self.bytes_fetched += len(data)
+        with self._roundtrip(1):
+            data = self.backend.get_range(key, start, length)
+            self.bytes_fetched += len(data)
         return data
 
 
@@ -711,34 +726,43 @@ class VSS:
                 raise TypeError(f"read_batch takes ReadSpecs, got {sp!r}")
         if not specs:
             return []
-        # read-your-writes: wait out any publish windows still queued in
-        # the ingest pipeline for the videos this batch touches, so
-        # mid-stream prefix reads observe everything already appended
-        if self._ingest is not None:
-            self._ingest.barrier({sp.name for sp in specs})
-        self.deferred.mark_busy()
-        try:
-            return self._read_batch(specs)
-        finally:
-            self.deferred.mark_idle()
+        # one ``read`` root span per spec, open from here (the ingest
+        # barrier included) to the return; None when telemetry is off —
+        # no span bookkeeping on the disabled path
+        with contextlib.ExitStack() as stack:
+            roots = [
+                stack.enter_context(self.tracer.span(
+                    "read", spec=sp.name, batch_size=len(specs)))
+                for sp in specs
+            ] if self.tracer.enabled else None
+            # read-your-writes: wait out any publish windows still
+            # queued in the ingest pipeline for the videos this batch
+            # touches, so mid-stream prefix reads observe everything
+            # already appended
+            if self._ingest is not None:
+                self._ingest.barrier({sp.name for sp in specs})
+            self.deferred.mark_busy()
+            try:
+                return self._read_batch(specs, roots)
+            finally:
+                self.deferred.mark_idle()
 
-    def _read_batch(self, specs: List[ReadSpec]) -> List[ReadResult]:
+    def _read_batch(
+        self, specs: List[ReadSpec], roots: Optional[List[_obs.Span]]
+    ) -> List[ReadResult]:
         snap = _CatalogSnapshot(self.catalog)
         resolved = [sp.resolve(snap.original(sp.name)) for sp in specs]
         if self.profiler is not None:
             # pure observation, after resolve and before planning: the
             # profile never changes what this batch plans or returns
             self.profiler.record_batch(resolved)
-        # per-spec trace roots (plan → fetch → decode → admit children);
-        # None when telemetry is off — zero span bookkeeping on the
-        # disabled path
-        roots: Optional[List[_obs.Span]] = None
-        if self.tracer.enabled:
-            roots = [
-                _obs.Span("read", spec=r.name, t0=r.s, t1=r.e,
-                          codec=r.codec, batch_size=len(specs))
-                for r in resolved
-            ]
+        traced = roots is not None
+        if traced:
+            for root, r in zip(roots, resolved):
+                root.attrs.update(t0=r.s, t1=r.e, codec=r.codec)
+
+        def root(i: int) -> Optional[_obs.Span]:
+            return roots[i] if traced else None
 
         # -- plan: one joint problem per (video, view-config) group --------
         groups: Dict[tuple, List[int]] = {}
@@ -746,30 +770,29 @@ class VSS:
             groups.setdefault(r.plan_key(), []).append(i)
         plans: List[Optional[ReadPlan]] = [None] * len(specs)
         for members in groups.values():
-            for i, plan in zip(
-                members,
-                self._plan_group([resolved[i] for i in members], snap),
-            ):
+            with self.tracer.span("plan", parent=root(members[0])) as sp:
+                group_plans = self._plan_group(
+                    [resolved[i] for i in members], snap)
+            for i, plan in zip(members, group_plans):
                 plans[i] = plan
-        if roots is not None:
+            if sp is not None:
+                for _ in members:
+                    self._m_plan_seconds.observe(sp.dur_s)
+                self._share(sp, roots, members, lambda i: dict(
+                    segments=len(plans[i].segments),
+                    group_size=len(members)))
+        if traced:
             self._m_specs.inc(len(specs))
             self._m_groups.inc(len(groups))
             self._m_coalesced.inc(len(specs) - len(groups))
-            for i, plan in enumerate(plans):
-                self._m_plan_seconds.observe(plan.plan_seconds)
-                sp = _obs.Span(
-                    "plan", segments=len(plan.segments),
-                    group_size=len(groups[resolved[i].plan_key()]),
-                )
-                sp.dur_s = plan.plan_seconds
-                roots[i].children.append(sp)
 
         # -- prefetch: one batch_get per plan group, deduped per video.
         # A single-spec batch (the read()/read_spec path) streams
         # instead: there is nothing to share, and the per-run-group
         # fetch pattern has the lower peak memory (no blob/decode
         # retention across the call) — its _BatchIO only carries the
-        # telemetry counters.
+        # telemetry counters, and its fetches are inline stages of the
+        # spec's decode.
         single = len(specs) == 1
         ios: Dict[str, _BatchIO] = {
             name: _BatchIO(self.backend, stream=single)
@@ -778,22 +801,26 @@ class VSS:
         if not single:
             for members in groups.values():
                 io = ios[resolved[members[0]].name]
-                keys: List[str] = []
-                claims: List[Tuple[int, int, List[str]]] = []
-                for i in members:
-                    objs = self._plan_objects(plans[i])
-                    keys.extend(g.path for g in objs)
-                    if roots is not None:
-                        claims.append(
-                            (i, len(objs), self._claim_fetches(io, objs))
-                        )
-                secs0 = io.fetch_seconds
-                io.prefetch(keys)
-                if roots is not None:
-                    self._fetch_spans(
-                        roots, io, claims, io.fetch_seconds - secs0
-                    )
-        elif roots is not None:
+                objs = {i: self._plan_objects(plans[i]) for i in members}
+                users = [i for i in members if objs[i]]
+                if not users:
+                    continue
+                # priced and attributed before the fetch: first claimer
+                # of each object, in member order
+                claims = ({i: self._claim_fetches(io, objs[i])
+                           for i in users} if traced else None)
+                with self.tracer.span("fetch", parent=root(users[0])) as sp:
+                    io.prefetch([g.path for i in users for g in objs[i]])
+                if sp is not None:
+                    self._m_dedup.inc(sum(len(objs[i]) - len(claims[i])
+                                          for i in users))
+                    self._share(sp, roots, users, lambda i: dict(
+                        objects=len(claims[i]),
+                        bytes=sum(io.fetched_sizes.get(k, 0)
+                                  for k in claims[i]),
+                        planned=len(objs[i]),
+                        dedup_hits=len(objs[i]) - len(claims[i])))
+        elif traced:
             # price the plan before execution fetches anything (a
             # tiered key must be costed at the tier that will actually
             # serve it, not the hot tier it lands in afterwards)
@@ -826,55 +853,34 @@ class VSS:
             plan, io = plans[i], ios[r.name]
             rkey = r.result_key()
             shared = rkey in done
-            if roots is not None:
-                t_exec = time.perf_counter()
-                decoded0, fetched0 = io.gops_decoded, io.objects_fetched
-                bytes0, secs0 = io.bytes_fetched, io.fetch_seconds
-            if shared:
-                frames, encoded = done[rkey]
-                # duplicates share the execution, not the buffers: each
-                # result stays independently mutable, as it would be
-                # from sequential reads
-                frames = None if frames is None else frames.copy()
-                encoded = None if encoded is None else list(encoded)
-            elif r.codec != "rgb":
-                frames = None
-                encoded = self._execute_encoded(
-                    plan, r.roi, r.resolution, r.fps, r.codec, r.scale_to, io
-                )
-                done[rkey] = (frames, encoded)
-            else:
-                encoded = None
-                frames = self._execute(plan, r.roi, r.resolution, r.fps, io)
-                done[rkey] = (frames, encoded)
-                if self.enable_deferred:
-                    self.deferred.on_uncompressed_read(r.name)
-            if roots is not None:
-                root = roots[i]
+            decoded0 = io.gops_decoded
+            with self.tracer.span("decode", parent=root(i)) as sp:
                 if shared:
-                    self._m_dup_shared.inc()
-                    root.children.append(
-                        _obs.Span("decode", shared=True, gops=0)
+                    frames, encoded = done[rkey]
+                    # duplicates share the execution, not the buffers:
+                    # each result stays independently mutable, as it
+                    # would be from sequential reads
+                    frames = None if frames is None else frames.copy()
+                    encoded = None if encoded is None else list(encoded)
+                elif r.codec != "rgb":
+                    frames = None
+                    encoded = self._execute_encoded(
+                        plan, r.roi, r.resolution, r.fps, r.codec,
+                        r.scale_to, io
                     )
+                    done[rkey] = (frames, encoded)
                 else:
-                    fetch_s = io.fetch_seconds - secs0
-                    if io.objects_fetched > fetched0:
-                        # streaming path: fetches happened inside the
-                        # execution — emit the fetch span from deltas
-                        fsp = _obs.Span(
-                            "fetch", inline=True,
-                            objects=io.objects_fetched - fetched0,
-                            bytes=io.bytes_fetched - bytes0,
-                        )
-                        fsp.dur_s = fetch_s
-                        root.children.append(fsp)
-                    dsp = _obs.Span(
-                        "decode", gops=io.gops_decoded - decoded0
-                    )
-                    dsp.dur_s = max(
-                        0.0, (time.perf_counter() - t_exec) - fetch_s
-                    )
-                    root.children.append(dsp)
+                    encoded = None
+                    frames = self._execute(
+                        plan, r.roi, r.resolution, r.fps, io)
+                    done[rkey] = (frames, encoded)
+                    if self.enable_deferred:
+                        self.deferred.on_uncompressed_read(r.name)
+            if sp is not None:
+                sp.attrs["gops"] = io.gops_decoded - decoded0
+                if shared:
+                    sp.attrs["shared"] = True
+                    self._m_dup_shared.inc()
             results[i] = ReadResult(frames, r.codec, encoded, plan, r.fps,
                                     use_pallas=self.use_pallas)
 
@@ -886,15 +892,11 @@ class VSS:
                 continue
             admitted_keys.add(r.result_key())
             out = results[i]
-            t_admit = time.perf_counter()
-            self._admit(
-                r.name, out._frames, out.encoded, r.s, r.e, r.roi,
-                r.resolution, r.fps, r.codec, plans[i],
-            )
-            if roots is not None:
-                sp = _obs.Span("admit", video=r.name)
-                sp.dur_s = time.perf_counter() - t_admit
-                roots[i].children.append(sp)
+            with self.tracer.span("admit", parent=root(i), video=r.name):
+                self._admit(
+                    r.name, out._frames, out.encoded, r.s, r.e, r.roi,
+                    r.resolution, r.fps, r.codec, plans[i],
+                )
             admitted_names.append(r.name)
         if admitted_names:
             self.cache.evict_for_batch(admitted_names)
@@ -902,15 +904,12 @@ class VSS:
                 for name in dict.fromkeys(admitted_names):
                     _compact.compact(self.catalog, name, self.backend)
 
-        if roots is not None:
+        if traced:
             for io in ios.values():
                 self._m_objects.inc(io.objects_fetched)
                 self._m_fetch_bytes.inc(io.bytes_fetched)
                 self._m_decoded.inc(io.gops_decoded)
                 self._m_actual_io.inc(io.fetch_seconds)
-            for root in roots:
-                self.tracer.record(root.finish())
-
         return results
 
     # -- read-path telemetry helpers ----------------------------------------
@@ -935,26 +934,15 @@ class VSS:
         self._m_predicted_io.inc(predicted)
         return new_keys
 
-    def _fetch_spans(
-        self, roots: List[_obs.Span], io: _BatchIO,
-        claims: List[Tuple[int, int, List[str]]], fetch_wall: float,
-    ) -> None:
-        """One fetch span per plan-group member.  The group's batch_get
-        is one physical round-trip, so wall time is split across
-        members proportionally to their claimed objects; bytes come
-        from the actual blob sizes the fetch recorded."""
-        total = sum(len(ks) for _i, _n, ks in claims) or 1
-        for i, planned, new_keys in claims:
-            self._m_dedup.inc(planned - len(new_keys))
-            sp = _obs.Span(
-                "fetch",
-                objects=len(new_keys),
-                bytes=sum(io.fetched_sizes.get(k, 0) for k in new_keys),
-                planned=planned,
-                dedup_hits=planned - len(new_keys),
-            )
-            sp.dur_s = fetch_wall * (len(new_keys) / total)
-            roots[i].children.append(sp)
+    @staticmethod
+    def _share(sp: _obs.Span, roots: List[_obs.Span], members: List[int],
+               attrs) -> None:
+        """Record one group stage ``sp``, opened under the first
+        member's root, on every member's root: one real interval, with
+        each member's own ``attrs(i)``."""
+        sp.attrs.update(attrs(members[0]))
+        for i in members[1:]:
+            roots[i].children.append(sp.twin(**attrs(i)))
 
     # -- joint planning ----------------------------------------------------
     def _plan_group(
@@ -980,7 +968,6 @@ class VSS:
 
         plans: List[Optional[ReadPlan]] = [None] * len(members)
         for cs, ce, idxs in components:
-            t0 = time.perf_counter()
             runs = self._candidate_runs(
                 r0.name, cs, ce, r0.roi, r0.fps, r0.codec, r0.scale_to,
                 r0.spec.quality_eps_db, snap,
@@ -993,7 +980,6 @@ class VSS:
                 r0.roi,
             )
             selection = solve(problem, r0.spec.method or self.solver)
-            plan_seconds = time.perf_counter() - t0
             for i in idxs:
                 m = members[i]
                 indices = [
@@ -1013,7 +999,6 @@ class VSS:
                 )
                 plans[i] = ReadPlan(
                     list(sub_problem.segments), sub_problem, sub_sel, runs,
-                    plan_seconds,
                 )
         return plans
 
@@ -1277,8 +1262,10 @@ class VSS:
             )
             pieces.append(piece)
             touched.extend(gop_ids)
-        self.catalog.touch_gops(touched)
-        return np.concatenate(pieces, axis=0)
+        with _obs.Tracer.stage("touch"):
+            self.catalog.touch_gops(touched)
+        with _obs.Tracer.stage("assemble"):
+            return np.concatenate(pieces, axis=0)
 
     def _execute_encoded(
         self, plan: ReadPlan, roi: Box, resolution, out_fps, out_codec,
@@ -1303,7 +1290,8 @@ class VSS:
                     for _, chunk in _codec.split_into_gops(piece, out_codec)
                 )
             touched.extend(gop_ids)
-        self.catalog.touch_gops(touched)
+        with _obs.Tracer.stage("touch"):
+            self.catalog.touch_gops(touched)
         return out
 
     def _extract_encoded(
@@ -1353,6 +1341,7 @@ class VSS:
         # spatial crop box (ROI → this video's local pixel coords)
         lx0, ly0, lx1, ly1 = self._local_box(p, roi)
         ox = oy = 0  # origin of the loaded pixel region
+        parts: Optional[List[np.ndarray]] = None
         if p.tiles != (1, 1):
             frames, (ox, oy) = self._load_tiled_frames(
                 p, gops, (lx0, ly0, lx1, ly1), io
@@ -1368,16 +1357,18 @@ class VSS:
                 parts.append(self._load_gop_prefix(tail, hi, io))
             else:
                 parts = self._load_gops_frames(gops, io)
-            frames = np.concatenate(parts, axis=0)
-        base = gops[0].start_frame
-        frames = frames[f0 - base : f1 - base]
-        # frame-rate division
-        step = int(round(p.fps / out_fps))
-        if step > 1:
-            frames = frames[::step]
-        frames = frames[:, ly0 - oy : ly1 - oy, lx0 - ox : lx1 - ox]
-        # resample to the requested resolution
-        frames = resample(frames, resolution)
+        with _obs.Tracer.stage("assemble"):
+            if parts is not None:
+                frames = np.concatenate(parts, axis=0)
+            base = gops[0].start_frame
+            frames = frames[f0 - base : f1 - base]
+            # frame-rate division
+            step = int(round(p.fps / out_fps))
+            if step > 1:
+                frames = frames[::step]
+            frames = frames[:, ly0 - oy : ly1 - oy, lx0 - ox : lx1 - ox]
+            # resample to the requested resolution
+            frames = resample(frames, resolution)
         return frames, [g.gop_id for g in gops]
 
     def _decode_gop_bytes(self, data: bytes) -> np.ndarray:

@@ -4,8 +4,12 @@ exposition helpers.
 - `MetricsRegistry` / `default_registry` — counters, gauges, fixed-
   bucket histograms; exact per-component handles summed into process-
   wide series (``registry.py``).
-- `Tracer` / `Span` — per-`ReadSpec` plan→fetch→decode→admit span
-  trees with ring-buffer retention (``trace.py``).
+- `Tracer` / `Span` — per-`ReadSpec` span trees (``read`` → plan,
+  fetch, decode with its per-GOP inflate/upload/download, admit),
+  opened live around the work with wall and thread-CPU time, nested by
+  an ambient current span (`Tracer.stage`), mirrored as
+  ``vss.<name>`` ``jax.profiler`` annotations, and kept in a ring
+  buffer (``trace.py``).
 - `InstrumentedBackend` / `instrument_backend` — per-backend-kind op
   latency/bytes/error metrics, auto-applied by
   ``repro.storage.make_backend`` (``instrument.py``).
@@ -13,7 +17,7 @@ exposition helpers.
   ``/metrics``+``/healthz`` endpoint or of this process' registry.
 
 Set ``VSS_TELEMETRY=0`` to disable the default registry process-wide
-(no-op handles, no instrumentation wrappers)."""
+(no-op handles, no instrumentation wrappers, no spans)."""
 
 from repro.obs.registry import (
     ENV_TELEMETRY,

@@ -11,6 +11,7 @@ import urllib.request
 import numpy as np
 import pytest
 
+from repro.core.config import DeferredConfig, VSSConfig
 from repro.core.spec import ReadSpec
 from repro.core.store import VSS
 from repro.obs import (
@@ -243,6 +244,57 @@ def test_disabled_registry_overhead_guard():
     )
 
 
+def _memory_config(registry):
+    return VSSConfig(backend="memory", registry=registry, compaction=False,
+                     deferred=DeferredConfig(enabled=False))
+
+
+def test_disabled_registry_builds_no_span_and_enters_no_annotation(
+        tmp_path, monkeypatch):
+    """The overhead guard's contract for the read spans: on a disabled
+    registry a read builds no `Span` and enters no profiler annotation,
+    and a codec call outside any read builds none either."""
+    from repro.codec import tvc
+    from repro.obs import trace
+
+    built, entered = [], []
+    init = trace.Span.__init__
+
+    def counting_init(self, *a, **kw):
+        built.append(a[0])
+        init(self, *a, **kw)
+
+    def counting_annotation():
+        def make(name, **kw):
+            entered.append(name)
+            return trace.contextlib.nullcontext()
+        return make
+
+    monkeypatch.setattr(trace.Span, "__init__", counting_init)
+    monkeypatch.setattr(trace, "_annotation", counting_annotation)
+    clip = np.random.RandomState(0).randint(0, 255, (10, 24, 32, 3),
+                                            np.uint8)
+
+    def read_through(registry, name):
+        store = VSS(str(tmp_path / name), config=_memory_config(registry))
+        try:
+            store.write("v", clip, fps=30.0, codec="tvc-hi", gop_frames=5)
+            assert store.read("v", cache=False).frames.shape == clip.shape
+        finally:
+            store.close()
+
+    read_through(MetricsRegistry(enabled=False), "off")
+    enc = tvc.encode_gop(clip[:5], "tvc-hi")
+    assert tvc.decode_gop(enc).shape == (5, 24, 32, 3)
+    with Tracer.stage("inflate") as sp:
+        assert sp is None
+    assert built == [] and entered == []
+    # the same read with telemetry on does build them: the hooks see it
+    read_through(MetricsRegistry(), "on")
+    assert "read" in built and "inflate" in built
+    assert entered.count("vss.read") == built.count("read") == 1
+
+
 # ---------------------------------------------------------------------------
 # tracer
 # ---------------------------------------------------------------------------
@@ -384,7 +436,9 @@ def test_single_read_streams_but_still_counts(traced_vss):
     assert len(roots) == 1
     names = [c["name"] for c in roots[0]["children"]]
     assert names[0] == "plan" and "decode" in names
-    fetch = [c for c in roots[0]["children"] if c["name"] == "fetch"]
+    # streamed fetches happen during execution: under ``decode``
+    fetch = [c for d in roots[0]["children"] if d["name"] == "decode"
+             for c in d.get("children", []) if c["name"] == "fetch"]
     assert fetch and fetch[0]["attrs"]["inline"] is True
     assert fetch[0]["attrs"]["objects"] == 3
 
@@ -400,6 +454,122 @@ def test_trace_ring_is_bounded(tmp_path, clip):
         assert len(store.recent_traces()) == 4
     finally:
         store.close()
+
+
+def _walk(d, parent=None):
+    yield d, parent
+    for c in d.get("children", ()):
+        yield from _walk(c, d)
+
+
+# a span's end is its float start plus its float duration, both from one
+# integer clock: rounding may move an end by well under a microsecond
+_ROUNDING_S = 1e-6
+
+
+def test_live_spans_lie_inside_their_parents(traced_vss):
+    """Each span's ``t_wall`` is its real start: a child starts and ends
+    inside its parent's interval, the root opens before ``plan``, and a
+    group stage shared by a batch is one interval on every member."""
+    store, _ = traced_vss
+    store.read("v", t=(0.0, 0.5), cache=True)
+    store.read_batch([ReadSpec(name="v", t=(0.0, 1.0), cache=False),
+                      ReadSpec(name="v", t=(0.5, 1.5), cache=False)])
+    roots = store.recent_traces()
+    assert len(roots) == 3
+    assert len({r["read_id"] for r in roots}) == 3
+    for root in roots:
+        assert root["children"][0]["name"] == "plan"
+        assert root["t_wall"] < root["children"][0]["t_wall"]
+        for sp, parent in _walk(root):
+            assert 0.0 <= sp["cpu_s"] <= sp["dur_s"] + _ROUNDING_S
+            if parent is None:
+                continue
+            assert sp["t_wall"] >= parent["t_wall"]
+            assert (sp["t_wall"] + sp["dur_s"]
+                    <= parent["t_wall"] + parent["dur_s"] + _ROUNDING_S)
+    names = {sp["name"] for root in roots for sp, _ in _walk(root)}
+    assert {"read", "plan", "fetch", "decode", "admit", "inflate", "upload",
+            "download", "assemble", "touch"} <= names
+    batch = roots[1:]
+    for stage in ("plan", "fetch"):
+        (a,), (b,) = ([c for c in r["children"] if c["name"] == stage]
+                      for r in batch)
+        assert (a["t_wall"], a["dur_s"]) == (b["t_wall"], b["dur_s"])
+
+
+@pytest.mark.parametrize("codec,resid_bytes", [("tvc-hi", 1), ("tvc-ll", 2)])
+def test_decode_stages_per_gop_carry_the_bytes_of_the_shapes(
+        tmp_path, clip, codec, resid_bytes):
+    """One ``inflate``, ``upload`` and ``download`` per decoded GOP, in
+    that order, under ``decode``, with the bytes the GOP's shape gives:
+    the I-frame and the residuals at the tier's width go in, uint8
+    frames come out."""
+    store = VSS(str(tmp_path / "vss"),
+                config=_memory_config(MetricsRegistry()))
+    try:
+        store.write("v", clip[:20], fps=30.0, codec=codec, gop_frames=5)
+        store.read("v", cache=False)
+        (root,) = store.recent_traces()
+    finally:
+        store.close()
+    t, (h, w, c) = 5, clip.shape[1:]
+    planes = h * w * c + (t - 1) * h * w * c * resid_bytes
+    (decode,) = [d for d in root["children"] if d["name"] == "decode"]
+    stages = [s for s in decode["children"]
+              if s["name"] in ("inflate", "upload", "download")]
+    assert decode["attrs"]["gops"] == 4
+    assert [s["name"] for s in stages] == ["inflate", "upload",
+                                           "download"] * 4
+    for s in stages:
+        if s["name"] == "inflate":
+            assert s["attrs"]["bytes_out"] == planes
+            assert 0 < s["attrs"]["bytes_in"] < planes
+        elif s["name"] == "upload":
+            assert s["attrs"]["bytes"] == planes
+        else:
+            assert s["attrs"]["bytes"] == t * h * w * c
+    # the three follow one another: no decode host time between them
+    for a, b in zip(stages, stages[1:]):
+        assert a["t_wall"] + a["dur_s"] <= b["t_wall"] + _ROUNDING_S
+
+
+def test_profiler_trace_holds_the_read_spans_on_a_host_plane(
+        tmp_path, clip):
+    """The spans are ``vss.<name>`` annotations in a ``jax.profiler``
+    trace, each carrying its root's ``read_id``."""
+    import glob
+
+    import jax
+
+    store = VSS(str(tmp_path / "vss"),
+                config=_memory_config(MetricsRegistry()))
+    try:
+        store.write("v", clip[:10], fps=30.0, codec="tvc-hi", gop_frames=5)
+        store.read("v", cache=False)  # compiles outside the trace
+        jax.profiler.start_trace(str(tmp_path / "trace"))
+        try:
+            store.read("v", cache=False)
+        finally:
+            jax.profiler.stop_trace()
+        read_id = store.recent_traces()[-1]["read_id"]
+    finally:
+        store.close()
+    (path,) = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                        recursive=True)
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("vss."):
+                    found.setdefault(ev.name, []).append(dict(ev.stats))
+    assert {"vss.read", "vss.decode", "vss.inflate", "vss.upload",
+            "vss.download"} <= set(found)
+    assert len(found["vss.inflate"]) == 2
+    assert all(st["read_id"] == read_id
+               for evs in found.values() for st in evs)
 
 
 def test_ingest_stats_view_matches_registry(traced_vss):
