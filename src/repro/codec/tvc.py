@@ -41,7 +41,7 @@ except ImportError:  # pragma: no cover - environment-dependent
     zstandard = None
 
 from repro.kernels import ops
-from repro.obs import Tracer
+from repro.obs import LabelledCounter, Tracer
 
 RGB = "rgb"  # raw uncompressed uint8 frames
 
@@ -142,14 +142,18 @@ def _zstd(data: bytes, level: int) -> bytes:
     return zlib.compress(data, min(max(level, 1), 9))
 
 
+def _zstd_decompressor():
+    if zstandard is None:
+        raise RuntimeError(
+            "GOP payload is zstd-compressed but the zstandard wheel"
+            " is not installed"
+        )
+    return zstandard.ZstdDecompressor()
+
+
 def _unzstd(data: bytes) -> bytes:
     if data[:4] == _ZSTD_FRAME_MAGIC:
-        if zstandard is None:
-            raise RuntimeError(
-                "GOP payload is zstd-compressed but the zstandard wheel"
-                " is not installed"
-            )
-        return zstandard.ZstdDecompressor().decompress(data)
+        return _zstd_decompressor().decompress(data)
     return zlib.decompress(data)
 
 
@@ -203,18 +207,6 @@ def _chunked_gop(
     return EncodedGOP(codec, shape, b"".join(chunks), tuple(offsets))
 
 
-def _raw_payload(enc: EncodedGOP) -> bytes:
-    """Decompressed ``iframe_u8 ++ residuals`` bytes for a TVC GOP,
-    whatever its payload format (chunked v2 or legacy single-stream)."""
-    if enc.offsets is not None:
-        off = enc.offsets
-        return b"".join(
-            _unzstd(enc.payload[off[i]:off[i + 1]])
-            for i in range(len(off) - 1)
-        )
-    return _unzstd(enc.payload)
-
-
 def prefix_gop(enc: EncodedGOP, hi: int) -> EncodedGOP:
     """The sub-GOP holding frames [0, hi) of ``enc``, sliced without any
     decode work.  Requires a random-access payload (RGB, or a chunked
@@ -232,23 +224,94 @@ def prefix_gop(enc: EncodedGOP, hi: int) -> EncodedGOP:
                       enc.payload[: enc.offsets[hi]], enc.offsets[: hi + 1])
 
 
+INFLATE_METRIC = "vss_codec_inflate_total"
+
+_inflates = LabelledCounter(
+    INFLATE_METRIC,
+    "TVC GOPs inflated on the host, by path: into (zstd into the planes"
+    " buffer), copy (zlib, one copy), stream (legacy single stream)",
+    ("path",),
+)
+
+
+def _read_into(reader, slot: memoryview) -> None:
+    """Fill ``slot`` from a zstd stream reader that must end there."""
+    n, got = len(slot), 0
+    while got < n:
+        k = reader.readinto(slot[got:])
+        if not k:
+            raise ValueError(
+                f"chunk inflates to {got} bytes, its frame has {n}")
+        got += k
+    if reader.read(1):
+        raise ValueError(f"chunk inflates past its frame's {n} bytes")
+
+
+def inflate_planes(enc: EncodedGOP) -> Tuple[np.ndarray, str]:
+    """The decompressed ``iframe_u8 ++ residuals`` of a TVC GOP as one
+    uint8 buffer, and the path that made it: ``into`` (zstd chunks
+    inflated straight into their slots), ``copy`` (zlib chunks, one copy
+    each) or ``stream`` (a legacy single-stream payload).
+
+    Raises ValueError unless the payload inflates to exactly the planes
+    its shape gives, chunk by chunk; a corrupt stream raises its codec's
+    own error."""
+    t, h, w, c = enc.shape
+    isz = h * w * c
+    rsz = isz * np.dtype(TIERS[enc.codec].resid_dtype).itemsize
+    size = isz + (t - 1) * rsz
+    if enc.offsets is None:
+        buf = np.frombuffer(_unzstd(enc.payload), np.uint8)
+        if buf.size != size:
+            raise ValueError(
+                f"payload inflates to {buf.size} bytes, its shape has {size}")
+        return buf, "stream"
+    off = enc.offsets
+    if len(off) != t + 1:
+        raise ValueError(f"{len(off) - 1} chunks for a GOP of {t} frames")
+    buf = np.empty(size, np.uint8)
+    out, src = memoryview(buf), memoryview(enc.payload)
+    dctx = None  # one per call: reads decode GOPs on many threads
+    lo = 0
+    for i in range(t):
+        hi = lo + (rsz if i else isz)
+        chunk, slot = src[off[i]:off[i + 1]], out[lo:hi]
+        if chunk[:4] == _ZSTD_FRAME_MAGIC:
+            if dctx is None:
+                dctx = _zstd_decompressor()
+            _read_into(dctx.stream_reader(chunk), slot)
+            path = "into"
+        else:
+            raw = zlib.decompress(chunk, bufsize=len(slot))
+            if len(raw) != len(slot):
+                raise ValueError(f"chunk inflates to {len(raw)} bytes, "
+                                 f"its frame has {len(slot)}")
+            slot[:] = raw
+            path = "copy"
+        lo = hi
+    return buf, path
+
+
 def gop_planes(enc: EncodedGOP) -> Tuple[np.ndarray, np.ndarray]:
     """The stored planes of a TVC GOP: the I-frame ``(C, H, W)`` uint8
-    and the quantized residuals ``(T-1, C, H, W)`` in the tier's dtype.
+    and the quantized residuals ``(T-1, C, H, W)`` in the tier's dtype,
+    both views of one buffer (`inflate_planes`).
 
     The payload is channel-planar, exactly as encoded: the I-frame
-    followed by the residuals.  Inside a traced read this is the
-    ``inflate`` stage."""
+    followed by the residuals.  Each per-frame chunk inflates straight
+    into its own slot of the buffer, so no payload-sized temporary is
+    made.  Inside a traced read this is the ``inflate`` stage."""
     t, h, w, c = enc.shape
-    tier = TIERS[enc.codec]
+    isz = h * w * c
     with Tracer.stage("inflate", bytes_in=len(enc.payload)) as sp:
-        raw = _raw_payload(enc)
-        isz = h * w * c
-        iframe = np.frombuffer(raw[:isz], np.uint8).reshape(c, h, w)
-        resid = np.frombuffer(raw[isz:], tier.resid_dtype).reshape(
+        buf, path = inflate_planes(enc)
+        iframe = buf[:isz].reshape(c, h, w)
+        resid = buf[isz:].view(TIERS[enc.codec].resid_dtype).reshape(
             t - 1, c, h, w)
         if sp is not None:
-            sp.attrs["bytes_out"] = len(raw)
+            sp.attrs["bytes_out"] = buf.nbytes
+            sp.attrs["path"] = path
+    _inflates.inc(path)
     return iframe, resid
 
 

@@ -21,7 +21,6 @@ Every function here has a matching oracle in :mod:`repro.kernels.ref`.
 from __future__ import annotations
 
 import os
-import threading
 from typing import Tuple
 
 import jax
@@ -33,46 +32,28 @@ from repro.kernels import histogram as _hist
 from repro.kernels import mse as _mse
 from repro.kernels import ref
 from repro.kernels import transcode as _tc
+from repro.obs.registry import LabelledCounter
 
 SUBLANE = 8
 LANE = 128
 
 DISPATCH_METRIC = "vss_kernel_dispatch_total"
 
-_dispatch_lock = threading.Lock()
-_dispatch_counters: dict = {}
+_dispatches = LabelledCounter(
+    DISPATCH_METRIC,
+    "codec/joint kernel calls by the implementation that ran",
+    ("op", "path"),
+)
 
 
 def _count(op: str, path: str) -> None:
     """Count one call of ``op`` served by ``path`` (default registry)."""
-    key = (op, path)
-    counter = _dispatch_counters.get(key)
-    if counter is None:
-        from repro.obs import default_registry
-
-        with _dispatch_lock:
-            counter = _dispatch_counters.get(key)
-            if counter is None:
-                counter = default_registry().counter(
-                    DISPATCH_METRIC,
-                    "codec/joint kernel calls by the implementation that ran",
-                    labels={"op": op, "path": path},
-                )
-                _dispatch_counters[key] = counter
-    counter.inc()
+    _dispatches.inc(op, path)
 
 
 def dispatch_counts() -> dict:
     """``{(op, path): calls}`` so far in this process (default registry)."""
-    from repro.obs import default_registry
-
-    reg = default_registry()
-    with _dispatch_lock:
-        keys = list(_dispatch_counters)
-    return {
-        k: reg.value(DISPATCH_METRIC, {"op": k[0], "path": k[1]})
-        for k in keys
-    }
+    return _dispatches.counts()
 
 
 def _resolve(op: str, use_pallas, interpret):

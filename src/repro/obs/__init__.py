@@ -26,6 +26,7 @@ from repro.obs.registry import (
     Counter,
     Gauge,
     Histogram,
+    LabelledCounter,
     MetricsRegistry,
     default_registry,
 )
@@ -39,6 +40,7 @@ __all__ = [
     "Counter",
     "Gauge",
     "Histogram",
+    "LabelledCounter",
     "MetricsRegistry",
     "default_registry",
     "DEFAULT_TRACE_CAPACITY",

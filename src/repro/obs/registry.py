@@ -486,3 +486,39 @@ def default_registry() -> MetricsRegistry:
                 )
                 _default = MetricsRegistry(enabled=enabled)
     return _default
+
+
+class LabelledCounter:
+    """One counter family of the default registry, counted by label
+    values from a hot path: the handle of each label set is made on its
+    first use and cached, so a count after that takes no registry lock
+    (``vss_kernel_dispatch_total``, ``vss_codec_inflate_total``)."""
+
+    def __init__(self, name: str, help: str, labels: Sequence[str]):
+        self.name = name
+        self.help = help
+        self.labels = tuple(labels)
+        self._lock = threading.Lock()
+        self._handles: Dict[Tuple[str, ...], Counter] = {}
+
+    def inc(self, *values: str) -> None:
+        """Count one event under the label values, in ``labels`` order."""
+        handle = self._handles.get(values)
+        if handle is None:
+            with self._lock:
+                handle = self._handles.get(values)
+                if handle is None:
+                    handle = default_registry().counter(
+                        self.name, self.help,
+                        labels=dict(zip(self.labels, values)))
+                    self._handles[values] = handle
+        handle.inc()
+
+    def counts(self) -> Dict[Tuple[str, ...], float]:
+        """``{label values: count}`` so far in this process, for every
+        label set counted here."""
+        reg = default_registry()
+        with self._lock:
+            keys = list(self._handles)
+        return {k: reg.value(self.name, dict(zip(self.labels, k)))
+                for k in keys}

@@ -11,11 +11,13 @@ import urllib.request
 import numpy as np
 import pytest
 
+from repro.codec import tvc
 from repro.core.config import DeferredConfig, VSSConfig
 from repro.core.spec import ReadSpec
 from repro.core.store import VSS
 from repro.obs import (
     LATENCY_BUCKETS,
+    LabelledCounter,
     MetricsRegistry,
     Span,
     Tracer,
@@ -50,6 +52,35 @@ def test_counter_and_gauge_basics():
     assert reg.value("t_total") == 3.5
     assert reg.value("t_gauge") == 5.0
     assert reg.value("never_registered") == 0.0
+
+
+def test_labelled_counter_counts_each_label_set_in_the_default_registry():
+    """A `LabelledCounter` keeps one cached handle per label set, counts
+    into the default registry's series, and reports what it counted."""
+    from repro.obs import default_registry
+
+    lc = LabelledCounter("t_labelled_total", "help", ("op", "path"))
+    errs = []
+
+    def work():
+        try:
+            for _ in range(100):
+                lc.inc("a", "x")
+                lc.inc("b", "y")
+        except Exception as e:  # pragma: no cover - reported below
+            errs.append(e)
+
+    threads = [threading.Thread(target=work) for _ in range(4)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    assert not errs
+    assert len(lc._handles) == 2
+    assert lc.counts() == {("a", "x"): 400.0, ("b", "y"): 400.0}
+    reg = default_registry()
+    assert reg.value("t_labelled_total", {"op": "a", "path": "x"}) == 400.0
+    assert reg.value("t_labelled_total", {"op": "b", "path": "z"}) == 0.0
 
 
 def test_histogram_bucket_math():
@@ -254,7 +285,6 @@ def test_disabled_registry_builds_no_span_and_enters_no_annotation(
     """The overhead guard's contract for the read spans: on a disabled
     registry a read builds no `Span` and enters no profiler annotation,
     and a codec call outside any read builds none either."""
-    from repro.codec import tvc
     from repro.obs import trace
 
     built, entered = [], []
@@ -504,7 +534,9 @@ def test_decode_stages_per_gop_carry_the_bytes_of_the_shapes(
     """One ``inflate``, ``upload`` and ``download`` per decoded GOP, in
     that order, under ``decode``, with the bytes the GOP's shape gives:
     the I-frame and the residuals at the tier's width go in, uint8
-    frames come out."""
+    frames come out.  ``inflate`` names its path: zstd chunks inflate
+    into the planes buffer, zlib chunks through one copy."""
+    path = "into" if tvc.zstandard is not None else "copy"
     store = VSS(str(tmp_path / "vss"),
                 config=_memory_config(MetricsRegistry()))
     try:
@@ -525,6 +557,7 @@ def test_decode_stages_per_gop_carry_the_bytes_of_the_shapes(
         if s["name"] == "inflate":
             assert s["attrs"]["bytes_out"] == planes
             assert 0 < s["attrs"]["bytes_in"] < planes
+            assert s["attrs"]["path"] == path
         elif s["name"] == "upload":
             assert s["attrs"]["bytes"] == planes
         else:
