@@ -1,6 +1,7 @@
 """Codec, host stage (``codec/tvc.py`` ``gop_planes``), in ms per GOP:
 the mean duration of the window's ``inflate`` spans, each one GOP's
-per-frame zstd (or zlib) chunks inflated and joined on the host."""
+per-frame zstd (or zlib) chunks inflated on the host, each straight into
+its slot of one planes buffer."""
 
 
 def spans(node, name):

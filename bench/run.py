@@ -5,8 +5,9 @@
 
 The cell, its configuration (``bench/configs/<config>.json``) and its
 traffic mix (``bench/traffic/<mix>.json``) are found by the names in
-``BENCHMARK.json``; per-layer metrics are read by
-``bench/layers/<metric>.py``. One run builds the cell's store from the
+``BENCHMARK.json``; the mix names its loop (``bench/loops/<loop>.py``)
+and its request kinds (``bench/kinds/<kind>.json``); per-layer metrics
+are read by ``bench/layers/<metric>.py``. One run builds the cell's store from the
 seed, warms up every shape its traffic asks for, measures for
 ``--seconds`` seconds, reads the device's peak memory, closes the
 program, and compares a seeded sample of the answers with the plain
@@ -43,8 +44,10 @@ import numpy as np  # noqa: E402
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+if __name__ == "__main__":  # the loops import this harness as bench.run
+    sys.modules.setdefault("bench.run", sys.modules[__name__])
 
-from bench import gen, reference, stats, trace_reduce, video  # noqa: E402
+from bench import gen, reference, trace_reduce, video  # noqa: E402
 
 OVERRIDES = ("VSS_USE_PALLAS", "REPRO_FORCE_PALLAS")
 OFF_KERNEL = ("oracle", "interpret")  # codec paths a chip run may not take
@@ -97,13 +100,30 @@ def cell_metrics(bench: dict, cell: str, trace: bool) -> List[dict]:
                              else [])]
 
 
-def read_layer(root: str, name: str, ctx) -> Optional[float]:
-    path = os.path.join(root, "bench", "layers", name + ".py")
+def load_module(root: str, folder: str, name: str):
+    """``bench/<folder>/<name>.py`` as a module of its own."""
+    path = os.path.join(root, "bench", folder, name + ".py")
     spec = importlib.util.spec_from_file_location(
-        "bench_layer_" + name.replace(".", "_"), path)
+        f"bench_{folder}_" + name.replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return mod
+
+
+def read_layer(root: str, name: str, ctx) -> Optional[float]:
+    return load_module(root, "layers", name).read(ctx)
+
+
+def run_loop(root: str, run) -> Dict:
+    """Runs the mix's loop, ``bench/loops/<loop>.py``."""
+    name = run.mix["loop"]
+    return getattr(load_module(root, "loops", name), name)(run)
+
+
+def load_kinds(root: str, mix: dict) -> Dict[str, dict]:
+    """The request kinds a mix names, ``bench/kinds/<kind>.json``."""
+    return {e["kind"]: load_json(root, "bench", "kinds", e["kind"] + ".json")
+            for e in mix.get("requests", [])}
 
 
 class CompileCounter:
@@ -142,7 +162,8 @@ def open_store(root: str, cfg: dict):
 
 def build_store(vss, lay: gen.Layout, seed: int) -> None:
     """``lay.cameras`` cameras of ``lay.gops`` GOPs, written GOP by GOP
-    through pipelined writers, one thread per camera."""
+    through pipelined writers, one thread per camera, with the
+    configuration's writer fields (``lay.write``)."""
     from repro.core.spec import WriteSpec
 
     errors: List[BaseException] = []
@@ -152,7 +173,7 @@ def build_store(vss, lay: gen.Layout, seed: int) -> None:
             src = video.Camera(lay.w, lay.h, video.camera_seed(seed, cam))
             w = vss.writer_spec(WriteSpec(name=f"cam{cam}", fps=lay.fps,
                                           codec=lay.codec,
-                                          gop_frames=lay.gop))
+                                          gop_frames=lay.gop, **lay.write))
             for g in range(lay.gops):
                 w.append(src.frames(g * lay.gop, (g + 1) * lay.gop))
             w.close()
@@ -272,7 +293,7 @@ def annotate(run, name: str):
         run.profiler.calls.append([name, a, time.time_ns()])
 
 
-# -- loops -------------------------------------------------------------------
+# -- warm-up -----------------------------------------------------------------
 
 def warm_up(run) -> None:
     """Every shape the mix asks for: its request on a one-camera store of
@@ -286,74 +307,13 @@ def warm_up(run) -> None:
         vss.close()
 
 
-def closed_read_batch(run) -> Dict:
-    lay, mix = run.layout, run.mix
-    vss = open_store(os.path.join(run.tmp, "store"), run.cfg)
-    run.closers.append(vss.close)
-    with phase("store"):
-        build_store(vss, lay, run.seed)
-    with phase("warm-up"):
-        warm_up(run)
-    work, kept, errors = [], [], []
-    lock = threading.Lock()
-
-    def consume(c: int) -> None:
-        # the consumer's answer that is checked: one drawn from the seed,
-        # each of those it received in the window as likely (a reservoir
-        # of one)
-        reqs = gen.closed_requests(mix, lay, run.seed, c)
-        pick = gen.rng_for(run.seed, 5, c)
-        answer = None
-        n = 0
-        while time.time() < run.t_end:
-            req = next(reqs)
-            issued = time.time()
-            try:
-                with annotate(run, "bench.read_batch"):
-                    frames = vss.read_batch([read_spec(req)])[0].frames
-            except Exception as e:  # noqa: BLE001 - counted as failed
-                with lock:
-                    errors.append(repr(e))
-                continue
-            done = time.time()
-            n += 1
-            if pick.random() * n < 1.0:
-                answer = (req, frames)
-            with lock:
-                work.append((issued, done, frames.shape[0] * frames.shape[1]
-                             * frames.shape[2] / 1e6))
-        if answer is not None:
-            with lock:
-                kept.append(answer)
-
-    threads = [threading.Thread(target=consume, args=(c,))
-               for c in range(mix["consumers"])]
-    run.open_window()
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    run.close_window()
-    spans = run.spans(vss)
-    return {
-        "attempted": len(work) + len(errors), "failed": len(errors),
-        "errors": errors[:3],
-        "metrics": {"read_Mpx_per_s": stats.window_rate(work, run.t0,
-                                                        run.t_end)},
-        "ctx": {"spans": spans, "gop_shape": (lay.gop, lay.h, lay.w, 3)},
-        "answers": kept,
-    }
-
-
-LOOPS = {"closed_read_batch": closed_read_batch}
-
-
 # -- correctness ---------------------------------------------------------------
 
 def check(run, out: Dict, shift: int) -> Dict[str, float]:
-    """The numbers compared for this run's answers: the program's with
-    ``shift`` 0, the control's (the reference one tier lower, put in the
-    program's place) with ``shift`` 1."""
+    """The numbers compared for this run's answers, each ``(request,
+    frames or served segments)``: the program's with ``shift`` 0, the
+    control's (the reference one tier lower, put in the program's place)
+    with ``shift`` 1."""
     lay = run.layout
     cams = [video.Camera(lay.w, lay.h, video.camera_seed(run.seed, cam))
             for cam in range(lay.cameras)]
@@ -361,16 +321,23 @@ def check(run, out: Dict, shift: int) -> Dict[str, float]:
     def source(cam: int, g: int) -> np.ndarray:
         return cams[cam].frames(g * lay.gop, (g + 1) * lay.gop)
 
-    want = reference.Store(source, fps=lay.fps, gop=lay.gop, tier=lay.codec)
+    tiled = tuple(lay.write.get("tiles", (1, 1))) != (1, 1)
+    want = reference.Store(source, fps=lay.fps, gop=lay.gop, tier=lay.codec,
+                           tiled=tiled)
     ctrl = reference.Store(source, fps=lay.fps, gop=lay.gop, tier=lay.codec,
-                           shift=1) if shift else None
+                           shift=1, tiled=tiled) if shift else None
     reqs = [req for req, _ in out["answers"]]
     for store in (want, ctrl) if shift else (want,):
         store.fill(reqs, REFERENCE_THREADS)
     n = 0
-    for req, frames in out["answers"]:
+    for req, answer in out["answers"]:
         exp = want.expect(req)
-        got = ctrl.expect(req) if shift else frames
+        if shift:
+            got = ctrl.expect(req)
+        elif isinstance(answer, list):  # served segments
+            got = reference.decode_segments(answer)
+        else:
+            got = answer
         n += reference.frames_differing(got, exp)
     return {"frames_differing": n}
 
@@ -382,12 +349,27 @@ LIMITS = {"frames_differing": 0}
 
 # -- one run -----------------------------------------------------------------
 
+def rehearsal_cfg(cfg: dict, mix: dict, kinds: Dict[str, dict]) -> dict:
+    """``cfg`` at `REHEARSAL`'s sizes, storing enough seconds for the
+    mix's longest request off any frame of a GOP."""
+    out = {**cfg, **REHEARSAL}
+    lay = gen.Layout(out)
+    gops = -(-(gen.longest_frames(mix, kinds, lay) + lay.gop - 1) // lay.gop)
+    out["stored_seconds"] = max(out["stored_seconds"],
+                                gops * lay.gop / lay.fps)
+    return out
+
+
 class Run:
-    def __init__(self, args, bench, cell, cfg, mix, tmp):
+    def __init__(self, args, bench, cell, cfg, mix, tmp, root=ROOT):
         self.args = args
+        if getattr(args, "rate", None):
+            mix = dict(mix, rate_per_s=args.rate)
         self.bench, self.cell, self.mix = bench, cell, mix
         self.rehearsal = args.cpu_rehearsal
-        self.cfg = dict(cfg, **REHEARSAL) if self.rehearsal else cfg
+        self.kinds = load_kinds(root, mix)
+        self.cfg = rehearsal_cfg(cfg, mix, self.kinds) if self.rehearsal \
+            else cfg
         self.layout = gen.Layout(self.cfg)
         self.seed, self.seconds = args.seed, float(args.seconds)
         self.tmp = tmp
@@ -448,10 +430,10 @@ def run_cell(args, root: str = ROOT) -> Dict:
     from repro.kernels import ops
 
     with tempfile.TemporaryDirectory(prefix="vss-bench-") as tmp:
-        run = Run(args, bench, cell, cfg, mix, tmp)
+        run = Run(args, bench, cell, cfg, mix, tmp, root)
         jax.monitoring.register_event_duration_secs_listener(run.compiles)
         try:
-            out = LOOPS[run.mix["loop"]](run)
+            out = run_loop(root, run)
             dev = jax.devices()[0]
             mem = (dev.memory_stats() or {}).get("peak_bytes_in_use")
             reduced = run.profiler.reduce(args.trace_dump)
@@ -522,6 +504,9 @@ def main(argv=None) -> int:
     ap.add_argument("--control", action="store_true",
                     help="also compare the control (the reference one "
                          "codec tier lower) and report its numbers")
+    ap.add_argument("--rate", type=float, default=None,
+                    help="requests/s of an open loop in place of the mix's "
+                         "(for a rate sweep)")
     ap.add_argument("--trace-dump", default=None,
                     help="write the loaded trace (device ops, bench spans) "
                          "as JSON to this path")

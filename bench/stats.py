@@ -31,3 +31,22 @@ def window_rate(work: Sequence[Tuple[float, float, float]], t0: float,
         raise ValueError("no work issued in the window")
     last = max(c for c, _ in counted)
     return sum(a for _, a in counted) / (last - t0)
+
+
+def percentile_of_attempts(done: Sequence[float], failed: int,
+                           p: float) -> float:
+    """`percentile` over every attempt, where each of the ``failed``
+    requests counts as slower than any that completed: infinite when the
+    percentile touches one of them."""
+    xs = sorted(done)
+    n = len(xs) + int(failed)
+    if n == 0:
+        raise ValueError("percentile of no values")
+    pos = (n - 1) * p / 100.0
+    lo = math.floor(pos)
+    hi = min(lo + 1, n - 1)
+    if lo >= len(xs) or (pos > lo and hi >= len(xs)):
+        return math.inf
+    if pos == lo:
+        return xs[lo]
+    return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)

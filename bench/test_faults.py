@@ -1,7 +1,9 @@
 """The comparison that decides ``correct``, shown to fail: the control
 (the plain reference one codec tier lower, in the program's place), and
 the timed path broken underneath a CPU rehearsal of each cell. The
-harness's look for a chip is the only part a rehearsal skips."""
+harness's look for a chip is the only part a rehearsal skips. The served
+cell's faults break what its answers pass through: the crop, the edge
+GOPs of a trim, the box pool, and the service's parked answers."""
 import argparse
 import threading
 
@@ -86,10 +88,75 @@ def _stale_reads(monkeypatch):
     monkeypatch.setattr(VSS, "read_batch", read_batch)
 
 
+def _crop_off_by_a_row(monkeypatch):
+    """Every crop box taken one row lower."""
+    from repro.core.store import VSS
+
+    real = VSS._local_box
+
+    def local_box(p, roi):
+        x0, y0, x1, y1 = real(p, roi)
+        return x0, y0 + 1, x1, y1 + 1
+
+    monkeypatch.setattr(VSS, "_local_box", staticmethod(local_box))
+
+
+def _edge_gop_not_reencoded(monkeypatch):
+    """A trim's edge GOPs served as stored, whole, with no re-encode."""
+    from repro import codec
+    from repro.core.store import VSS
+
+    real = VSS._extract_encoded
+
+    def extract_encoded(self, run_, a, b, out_codec, io=None):
+        encs, ids = real(self, run_, a, b, out_codec, io)
+        gops = {g.gop_id: g for g in run_.gops}
+        return [codec.deserialize_gop(self.backend.get(gops[i].path))
+                for i in ids], ids
+
+    monkeypatch.setattr(VSS, "_extract_encoded", extract_encoded)
+
+
+def _pool_rounded_down(monkeypatch):
+    """The box pool's means truncated instead of rounded."""
+    from repro.core import store
+
+    def resample(frames, resolution):
+        w, h = resolution
+        t, ih, iw, c = frames.shape
+        if (iw, ih) == (w, h):
+            return frames
+        f = ih // h
+        x = frames.astype(np.float32).reshape(t, h, f, w, f, c).mean((2, 4))
+        return np.floor(x).astype(np.uint8)
+
+    monkeypatch.setattr(store, "resample", resample)
+
+
+def _first_answer_for_all(monkeypatch):
+    """The service parks the first request's answer for every request."""
+    from repro.serving.service import VSSService
+
+    real = VSSService.park
+    first = {}
+    lock = threading.Lock()
+
+    def park(self, result):
+        with lock:
+            first.setdefault("res", result)
+            return real(self, first["res"])
+
+    monkeypatch.setattr(VSSService, "park", park)
+
+
 FAULTS = [
     ("vroad2k.scan", _altered_decode),
     ("vroad2k.scan", _half_batch),
     ("vroad2k.scan", _stale_reads),
+    ("vroad1k.query", _crop_off_by_a_row),
+    ("vroad1k.query", _edge_gop_not_reencoded),
+    ("vroad1k.query", _pool_rounded_down),
+    ("vroad1k.query", _first_answer_for_all),
 ]
 
 

@@ -159,3 +159,82 @@ def test_recorded_scan_self_time_is_a_small_part_of_a_read(scan_spans):
     mean_read_ms = 1e3 * sum(r["dur_s"] for r in scan_spans) / len(scan_spans)
     assert read("read_self_ms", scan_spans) < 0.2 * mean_read_ms
     assert 0 < read("read_offcpu_pct", scan_spans) < 100
+
+
+# -- the served cell's readers ------------------------------------------------
+
+SERVED = ("serve_wait_ms", "plan_ms_per_read", "execute_ms_per_read",
+          "coalesce_width")
+
+
+def served(spans, client_ms=None, coalesce=(0.0, 0)):
+    return SimpleNamespace(spans=spans, client_ms=client_ms or [],
+                           coalesce=coalesce)
+
+
+def read_served(name, ctx):
+    return run.read_layer(ROOT, name, ctx)
+
+
+def test_serve_wait_is_client_time_beyond_the_read():
+    # two requests of one batch (30 ms roots) took 50 and 70 ms at the
+    # client: 30 ms each outside the read
+    ctx = served(batch_of_two(), client_ms=[50.0, 70.0])
+    assert read_served("serve_wait_ms", ctx) == pytest.approx(30.0)
+
+
+def test_plan_and_execute_are_per_read():
+    ctx = served(batch_of_two())
+    # the group's plan (1 ms) on both reads; decodes of 10 and 12 ms
+    assert read_served("plan_ms_per_read", ctx) == pytest.approx(1.0)
+    assert read_served("execute_ms_per_read", ctx) == pytest.approx(11.0)
+
+
+def test_coalesce_width_is_requests_per_batch():
+    ctx = served([], coalesce=(10.0, 4))
+    assert read_served("coalesce_width", ctx) == pytest.approx(2.5)
+
+
+@pytest.mark.parametrize("name", SERVED)
+def test_served_readers_without_their_input_give_no_number(name):
+    assert read_served(name, served([])) is None
+    bare = [node("read", 0, 10)]
+    if name != "coalesce_width":
+        assert read_served(name, served(bare)) is None
+
+
+@pytest.fixture(scope="module")
+def query_spans():
+    with gzip.open(os.path.join(HERE, "fixtures", "query_spans.json.gz"),
+                   "rt") as f:
+        return json.load(f)
+
+
+def test_recorded_query_holds_one_read_tree_per_request(query_spans):
+    spans = query_spans["spans"]
+    assert len(spans) == len(query_spans["client_ms"]) == 40
+    for root in spans:
+        names = [c["name"] for c in root["children"]]
+        assert names in (["plan", "decode"], ["plan", "fetch", "decode"])
+    assert query_spans["coalesce"] == [40.0, 28]
+
+
+# each reader on the recorded query (``python3 bench/run.py --workload
+# vroad1k.query --cpu-rehearsal --trace 1 --seconds 1 --seed 3315000017``,
+# the spans, client times and coalescer counts kept as the readers
+# received them), as the reader computed it when the fixture was recorded
+RECORDED_QUERY = {
+    "serve_wait_ms": 96.49997993095398,
+    "plan_ms_per_read": 0.4210076,
+    "execute_ms_per_read": 13.8386544,
+    "coalesce_width": 1.4285714285714286,
+}
+
+
+@pytest.mark.parametrize("name", SERVED)
+def test_served_readers_on_a_recorded_query(name, query_spans):
+    ctx = served(query_spans["spans"], query_spans["client_ms"],
+                 tuple(query_spans["coalesce"]))
+    got = read_served(name, ctx)
+    assert math.isfinite(got) and got > 0
+    assert got == pytest.approx(RECORDED_QUERY[name], rel=1e-12)

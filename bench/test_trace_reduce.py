@@ -131,3 +131,14 @@ def test_recorded_tpu_trace():
     assert gaps == sorted(gaps, reverse=True)
     assert sum(gaps) <= red["window_s"] - red["busy_s"] + 1e-9
     assert trace_reduce.count_modules(red, "delta_decode_pallas") > 0
+
+
+def test_kernel_ops_wholly_inside_the_window_are_kept():
+    tr = hand_made()
+    hlo = ("%delta_decode_pallas.1 = f32[30,3,8,128]{} custom-call("
+           "f32[3,8,128]{} %i, s32[29,3,8,128]{} %r)")
+    tr["kernels"] = [[hlo, 30 * MS, MS],
+                     [hlo, 99 * MS, 5 * MS]]  # runs past the window's end
+    red = trace_reduce.reduce(tr)
+    assert red["kernels"] == [hlo]
+    assert trace_reduce.reduce(hand_made())["kernels"] == []

@@ -5,8 +5,11 @@ plain dict of ``[name, start_ns, dur_ns]`` lists: ``device``, the
 operations of the device planes (their ``XLA Ops`` line where a plane
 has one); ``modules``, the compiled programs they ran in (``XLA
 Modules``, named after the jitted function); and ``host``, the host
-spans the benchmark annotated (``bench.*``); and ``planes``, the names
-of the planes and their lines, for a reader of a dump.
+spans the benchmark annotated (``bench.*``); ``kernels``, the device's
+custom calls (the Pallas kernels) with the HLO text that names their
+shapes; and
+``planes``, the names of the planes and their lines, for a reader of a
+dump.
 ``reduce`` works on that dict alone, so it can be checked on a small
 recorded trace without a chip.
 """
@@ -34,15 +37,17 @@ def load(trace_dir: str) -> Dict[str, list]:
     print(f"trace file: {os.path.getsize(paths[-1])} bytes", file=sys.stderr,
           flush=True)
     data = ProfileData.from_file(paths[-1])
-    device, modules, host, planes = [], [], [], []
+    device, modules, host, planes, kernels = [], [], [], [], []
     for plane in data.planes:
         planes.append([plane.name, [ln.name for ln in plane.lines]])
         if plane.name.startswith("/device:"):
             lines = list(plane.lines)
             ops = [ln for ln in lines if ln.name == OPS_LINE] or lines
             for ln in ops:
-                device.extend([op_name(e.name), e.start_ns, e.duration_ns]
-                              for e in ln.events)
+                for e in ln.events:
+                    device.append([op_name(e.name), e.start_ns, e.duration_ns])
+                    if " custom-call(" in e.name:
+                        kernels.append([e.name, e.start_ns, e.duration_ns])
             for ln in lines:
                 if ln.name == MODULES_LINE:
                     modules.extend([e.name, e.start_ns, e.duration_ns]
@@ -54,10 +59,11 @@ def load(trace_dir: str) -> Dict[str, list]:
     # the planes of a TPU trace need not share a clock: the benchmark's
     # clock marks put the device's events on the host's
     offset = clock_offset(host, modules)
-    for ev in device + modules:
+    for ev in device + modules + kernels:
         ev[1] -= offset
     return {"device": device, "modules": modules, "host": host,
-            "planes": planes, "clock_offset_ns": offset}
+            "kernels": kernels, "planes": planes,
+            "clock_offset_ns": offset}
 
 
 def op_name(event: str) -> str:
@@ -121,6 +127,8 @@ def reduce(trace: Dict[str, list], top: int = 10) -> Dict:
     for name, s, d in trace.get("modules", []):
         if s >= w0 and s + d <= w1:
             by_module[name] = by_module.get(name, 0) + 1
+    kernels = [name for name, s, d in trace.get("kernels", [])
+               if s >= w0 and s + d <= w1]
     busy = union(clipped)
     busy_s = sum(b - a for a, b in busy) / 1e9
     edges = [w0] + [x for iv in busy for x in iv] + [w1]
@@ -142,6 +150,7 @@ def reduce(trace: Dict[str, list], top: int = 10) -> Dict:
         "window_s": (w1 - w0) / 1e9,
         "by_name": by_name,
         "by_module": by_module,
+        "kernels": kernels,
         "device_ops": [[n, v[1]] for n, v in ops[:top]],
         "idle_gaps": [[doing(a, b), (b - a) / 1e9] for a, b in gaps[:top]],
     }
