@@ -1528,25 +1528,46 @@ class VSS:
             self._m_tile_fetches.inc(len(need))
         if len(rows) * len(cols) < p.tiles[0] * p.tiles[1]:
             self._m_tile_reads.inc()
-        stitched: List[np.ndarray] = []
+        # every covering tile decoded (or taken from the batch) first,
+        # then joined in one ``stitch`` stage
+        tiles: Dict[Tuple[int, int, int], np.ndarray] = {}
+        decoded = 0
         for g in gops:
-            bands: List[np.ndarray] = []
             for r in rows:
-                band: List[np.ndarray] = []
                 for c in cols:
                     tkey = (g.gop_id, r, c)
                     if io is not None and tkey in io.decoded:
-                        band.append(io.decoded[tkey])
+                        tiles[tkey] = io.decoded[tkey]
                         continue
                     frames = self._decode_gop_bytes(blobs[tkey])
+                    decoded += 1
                     if io is not None:
                         io.gops_decoded += 1
                         if not io.stream:
                             io.decoded[tkey] = frames
-                    band.append(frames)
-                bands.append(np.concatenate(band, axis=2))
-            stitched.append(np.concatenate(bands, axis=1))
-        return np.concatenate(stitched, axis=0), (ox, oy)
+                    tiles[tkey] = frames
+        with _obs.Tracer.stage("stitch", tiles=len(tiles),
+                               decoded=decoded) as sp:
+            frames = self._stitch(tiles, gops, rows, cols)
+            if sp is not None:
+                sp.attrs["bytes"] = frames.nbytes
+        return frames, (ox, oy)
+
+    @staticmethod
+    def _stitch(
+        tiles: Dict[Tuple[int, int, int], np.ndarray],
+        gops: Sequence[GopMeta], rows: List[int], cols: List[int],
+    ) -> np.ndarray:
+        """The frames of a tiled region: each GOP's decoded tiles
+        (``tiles[gop_id, r, c]``) joined across columns, then rows, then
+        the GOPs in time.  Each tile leaves ``tiles`` as it is placed, so
+        a band's tiles are freed once it is joined."""
+        return np.concatenate([
+            np.concatenate([
+                np.concatenate([tiles.pop((g.gop_id, r, c)) for c in cols],
+                               axis=2)
+                for r in rows], axis=1)
+            for g in gops], axis=0)
 
     # ------------------------------------------------------------------
     # joint compression driver (§5.1) — candidate search + Algorithm 1
